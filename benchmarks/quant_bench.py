@@ -17,35 +17,23 @@ shows >= 4x fewer wire bytes than fp32.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 from pathlib import Path
 
 import numpy as np
 
+from repro.launch import train
 from repro.obs import write_bench
 
 
 def _run_driver(codec: str | None, steps: int) -> list[dict]:
-    argv = [sys.executable, "-m", "repro.launch.train", "--arch", "wdl-tiny",
-            "--steps", str(steps), "--batch-per-worker", "16",
-            "--log-every", "1", "--seed", "0"]
+    """One driver run in this process (on whatever backend JAX picked —
+    a child process could not share the accelerator) -> per-step
+    records."""
+    argv = ["--arch", "wdl-tiny", "--steps", str(steps),
+            "--batch-per-worker", "16", "--log-every", "1", "--seed", "0"]
     if codec is not None:
         argv += ["--codec", codec]
-    env = dict(os.environ, PYTHONPATH="src",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
-    res = subprocess.run(argv, capture_output=True, text=True, timeout=900,
-                         cwd=Path(__file__).parent.parent, env=env)
-    if res.returncode != 0:
-        raise RuntimeError(f"driver failed for codec={codec}:\n"
-                           f"{res.stderr[-2000:]}")
-    # step records go to stderr via obs.log_step; keep stdout too for
-    # drivers predating the structured-logging move
-    return [json.loads(l)
-            for l in (res.stdout + res.stderr).splitlines()
-            if l.startswith("{")]
+    return train.main(argv)
 
 
 def _census(codec: str | None) -> dict | None:

@@ -5,17 +5,10 @@
 #   scripts/ci.sh              # tier-1 (full suite, default selection) + bench smoke
 #   scripts/ci.sh --slow       # also run the @slow paper-scale tests
 #
-# The full suite runs — including tests/test_models_smoke.py and
-# tests/test_system.py, which exercise the repro.dist sharding layer (they
-# were broken at seed; fixed in PR 2).
-#
 # Wall-time notes: the suite is jit-bound, so CI (a) disables the
 # expensive LLVM passes (the compiled programs run for microseconds;
 # correctness-neutral — no fast-math) and (b) keeps a persistent XLA
-# compilation cache so reruns only pay tracing.  tests/conftest.py also
-# provides `--shard I/N` for machines with real parallelism (this 2-vCPU
-# sandbox time-shares one core; concurrent shards measured *slower* than
-# sequential here).
+# compilation cache so reruns only pay tracing.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
@@ -28,7 +21,7 @@ export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
 # inherit these: it measures runtime.
 TEST_ENV=(
   "XLA_FLAGS=--xla_backend_optimization_level=0 --xla_llvm_disable_expensive_passes=true${XLA_FLAGS:+ $XLA_FLAGS}"
-  "JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-/tmp/repro-ci-jax-cache}"
+  "JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
   "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0.2"
 )
 
@@ -73,8 +66,9 @@ python scripts/bench_check.py
 python -m repro.launch.train --arch wdl-tiny --steps 8 \
   --batch-per-worker 8 --esd-alpha 1 --pipeline-depth 2 --lookahead 8 \
   --prefetch 16 --exchange ragged \
-  --trace-out /tmp/repro-ci-trace.json --validate-timing > /dev/null
-python - /tmp/repro-ci-trace.json <<'EOF'
+  --trace-out benchmarks/results/ci_trace_quick.json --validate-timing \
+  > /dev/null
+python - benchmarks/results/ci_trace_quick.json <<'EOF'
 import json, sys
 doc = json.load(open(sys.argv[1]))
 assert doc["traceEvents"], "empty trace"

@@ -375,8 +375,8 @@ def to_shardings(specs: Any, mesh: Mesh | None = None):
     usable on host meshes.
     """
     if mesh is None:
-        n = len(jax.devices())
-        mesh = jax.make_mesh((n, 1), ("data", "model"))
+        from ..launch.mesh import make_host_mesh
+        mesh = make_host_mesh()
 
     def one(spec: P) -> NamedSharding:
         entries = []

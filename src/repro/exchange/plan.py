@@ -17,7 +17,9 @@ worker.  The exchange is described per ordered link ``(src, dst)``:
     concatenated payload (``offsets[i, n] == m``): the address a
     zero-copy sender would slice at.
   * ``buckets[i, j]`` — the on-wire block size: ``counts`` rounded up to
-    the next power of two (0 stays 0), capped at ``m``.  Bucketing
+    the next power of two (0 stays 0), capped at the fixed-shape block
+    ``padded_block`` (so the ragged wire never ships more than the
+    baseline) and at ``m``.  Bucketing
     quantizes block shapes so a compiled executor sees a handful of
     distinct shapes instead of one per step, while the pad it ships is
     at most the payload again (< 2x) — versus the fixed-shape baseline,
@@ -228,9 +230,6 @@ def compile_plan(assign: np.ndarray, n: int, m: int | None = None,
     np.add.at(counts, (src, assign), 1)
     offsets = np.zeros((n, n + 1), np.int64)
     np.cumsum(counts, axis=1, out=offsets[:, 1:])
-    buckets = bucket_sizes(counts, cap=cap)
-    schedule = tuple(sorted(np.unique(buckets[buckets > 0]).tolist(),
-                            reverse=True))
     n_dst = n
     n_src = n
     if active is not None:
@@ -251,6 +250,11 @@ def compile_plan(assign: np.ndarray, n: int, m: int | None = None,
         n_src = n_dst
 
     padded_block = int(max(counts.max(initial=0), -(-m // n_dst)))
+    # a bucket never outgrows the fixed-shape block: the ragged wire then
+    # ships at most what the padded baseline would
+    buckets = bucket_sizes(counts, cap=min(cap, padded_block))
+    schedule = tuple(sorted(np.unique(buckets[buckets > 0]).tolist(),
+                            reverse=True))
 
     payload = int(counts.sum()) * row_bytes
     ragged = int(buckets.sum()) * row_bytes

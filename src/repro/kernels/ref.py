@@ -6,16 +6,59 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def pooled_lookup_ref(table, ids, weights=None):
-    """sum_f table[ids[b,f]] * w[b,f]; PAD = -1."""
+def _pool(rows, ids, weights):
+    """Fold (B, F, E) looked-up rows over the fields in order (PAD ids,
+    -1, weigh 0): the summation order of the Pallas pooled kernels."""
     B, F = ids.shape
     if weights is None:
         weights = jnp.ones((B, F), jnp.float32)
-    valid = ids >= 0
-    ids_c = jnp.where(valid, ids, 0)
-    w = jnp.where(valid, weights, 0.0)
-    rows = table[ids_c].astype(jnp.float32)          # (B, F, E)
-    return (rows * w[..., None]).sum(axis=1)
+    w = jnp.where(ids >= 0, weights, 0.0).astype(jnp.float32)
+    acc = jnp.zeros((B, rows.shape[-1]), jnp.float32)
+    for f in range(F):
+        acc = acc + rows[:, f].astype(jnp.float32) * w[:, f, None]
+    return acc
+
+
+def pooled_lookup_ref(table, ids, weights=None):
+    """sum_f table[ids[b,f]] * w[b,f]; PAD = -1."""
+    return _pool(table[jnp.maximum(ids, 0)], ids, weights)
+
+
+def pooled_lookup_staged_ref(plane_rows, table, slots, ids, weights=None):
+    """Pooled lookup reading ``plane_rows[slots]`` where ``slots >= 0``
+    and ``table[ids]`` elsewhere; PAD ids = -1."""
+    from_plane = plane_rows[jnp.maximum(slots, 0)].astype(table.dtype)
+    rows = jnp.where((slots >= 0)[..., None], from_plane,
+                     table[jnp.maximum(ids, 0)])
+    return _pool(rows, ids, weights)
+
+
+def pooled_lookup_quant_ref(codes, scale, zp, ids, codec, weights=None):
+    """Pooled lookup over the dequantized table ``codes * scale + zp``."""
+    from ..quant.codecs import dequantize_rows
+    return pooled_lookup_ref(dequantize_rows(codes, scale, zp, codec), ids,
+                             weights)
+
+
+def gather_rows_ref(rows, slot_to_row, fill=-1):
+    """out[s] = rows[slot_to_row[s]] where slot_to_row[s] >= 0, else fill."""
+    got = rows[jnp.maximum(slot_to_row, 0)]
+    return jnp.where((slot_to_row >= 0)[:, None], got,
+                     jnp.asarray(fill, rows.dtype))
+
+
+def staged_gather_ref(plane_rows, table, src_rows):
+    """out[s] = table[src_rows[s]] if src_rows[s] >= 0 else plane_rows[s]."""
+    return jnp.where((src_rows >= 0)[:, None],
+                     table[jnp.maximum(src_rows, 0)], plane_rows)
+
+
+def gather_rows_quant_ref(rows, slot_to_row, codec, fill=-1):
+    """Gathered rows (PAD = constant ``fill`` rows) through
+    :func:`repro.quant.codecs.quantize_rows`."""
+    from ..quant.codecs import quantize_rows
+    return quantize_rows(gather_rows_ref(rows.astype(jnp.float32),
+                                         slot_to_row, fill), codec)
 
 
 def auction_bids_ref(cost, min_price, unassigned, eps):

@@ -49,6 +49,7 @@ from ..serve import (StreamConfig, make_serve_step, micro_batches,
                      plane_ages, refresh_plane, request_arrivals, seed_plane,
                      serve_cost_matrix, serve_decide)
 from ..serve.sim import _hot_set
+from .cache import use_compile_cache
 
 
 def build_parser():
@@ -190,6 +191,7 @@ def run_serve(args) -> dict:
     n_req = req_c.value
     out = {
         "mechanism": args.mechanism,
+        "n_arrivals": int(len(t_arr)),
         "n_requests": n_req,
         "p50_ms": lat_h.quantile(0.5) * 1e3,
         "p99_ms": lat_h.quantile(0.99) * 1e3,
@@ -208,7 +210,8 @@ def run_serve(args) -> dict:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-    run_serve(args)
+    use_compile_cache()
+    return run_serve(args)
 
 
 if __name__ == "__main__":

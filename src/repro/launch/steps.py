@@ -168,7 +168,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
     With neutral arrays (all active, zero bias, nominal t) the outputs
     are bitwise-equal to the non-elastic ragged stages.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core.dispatch_tpu import (dispatch_cap, esd_cost_matrix,
@@ -220,7 +220,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return shard_map(
             lambda s: decide_shard(esd_state, s), mesh=mesh,
             in_specs=(P(axis, None),), out_specs=(P(axis), P()),
-            check_rep=False)(sparse)
+            check_vma=False)(sparse)
 
     def advance_shard(s, d, l, a):
         if part is not None:
@@ -244,7 +244,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
             in_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
             out_specs=(P(axis, None), P(axis, None), P(axis), P(None, None),
                        P()),
-            check_rep=False)(sparse, dense, labels, assign)
+            check_vma=False)(sparse, dense, labels, assign)
         if sparse_esd:
             new_state, counts = esd_state_update_sparse(esd_state, need,
                                                         capacity, part,
@@ -269,7 +269,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return shard_map(
             lambda s, a: realized_shard(esd_state, s, a), mesh=mesh,
             in_specs=(P(axis, None), P(axis)), out_specs=P(),
-            check_rep=False)(sparse, assign)
+            check_vma=False)(sparse, assign)
 
     if not elastic:
         return decide, advance, realized_cost, out_rows
@@ -292,7 +292,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return shard_map(
             lambda s: decide_shard_e(state, s, t_arr, col_bias), mesh=mesh,
             in_specs=(P(axis, None),), out_specs=(P(axis), P()),
-            check_rep=False)(sparse)
+            check_vma=False)(sparse)
 
     @jax.jit
     def advance_e(esd_state, sparse, dense, labels, assign, active):
@@ -301,7 +301,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
             in_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
             out_specs=(P(axis, None), P(axis, None), P(axis), P(None, None),
                        P()),
-            check_rep=False)(sparse, dense, labels, assign)
+            check_vma=False)(sparse, dense, labels, assign)
         # mask BEFORE the update: a dead worker's stale planes must not
         # survive into the committed state (its rejoin is cold)
         state = mask_state(esd_state, active)
@@ -328,7 +328,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return shard_map(
             lambda s, a: realized_shard_e(state, s, a, t_arr, col_bias),
             mesh=mesh, in_specs=(P(axis, None), P(axis)), out_specs=P(),
-            check_rep=False)(sparse, assign)
+            check_vma=False)(sparse, assign)
 
     return decide_e, advance_e, realized_cost_e, out_rows
 
@@ -350,7 +350,7 @@ def make_dlrm_repair_stage(mesh, n: int, m: int, t_tran, *, part=None,
     so the original argmin stands.  Much cheaper than a full re-decide
     and runs at commit, off the decide stream.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core.dispatch_tpu import (changed_samples_mask, dispatch_cap,
@@ -373,7 +373,7 @@ def make_dlrm_repair_stage(mesh, n: int, m: int, t_tran, *, part=None,
         return shard_map(
             lambda s, a: repair_shard(committed_state, decide_state, s, a),
             mesh=mesh, in_specs=(P(axis, None), P(axis)),
-            out_specs=(P(axis), P()), check_rep=False)(sparse, assign)
+            out_specs=(P(axis), P()), check_vma=False)(sparse, assign)
 
     return repair
 

@@ -58,6 +58,8 @@ from ..obs import (MetricsRegistry, Tracer, format_report, get_tracer,
                    log_step, set_registry, set_tracer, validate_timing)
 from ..pipeline import (LookaheadWindow, PipelinedRunner, prefetch_candidates,
                         prefetch_init, prefetch_step, staged_membership)
+from .cache import use_compile_cache
+from .mesh import make_host_mesh
 from .steps import make_dlrm_esd_stages, make_dlrm_repair_stage
 from ..models import api, dlrm
 from ..optim import get_optimizer
@@ -74,9 +76,8 @@ from .steps import raise_on_overflow
 def run_dlrm(args):
     cfg = DLRM_CONFIGS[args.arch]
     wl = WORKLOADS[cfg.workload]
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
-    n = n_dev
+    mesh = make_host_mesh()
+    n = mesh.shape["data"]
     m = args.batch_per_worker
     k = m * n
     V = wl.vocab
@@ -186,6 +187,7 @@ def run_dlrm(args):
     shardings = to_shardings(param_specs(params, mesh=mesh), mesh)
     params = jax.device_put(params, shardings)
     batch_shd = lambda nd: NamedSharding(mesh, P(*(("data",) + (None,) * (nd - 1))))
+    replicated = NamedSharding(mesh, P())
 
     # PAD-masked loss only when PAD rows can actually appear: capacity
     # slack skews batches, and under a fault plan a dead worker's
@@ -214,7 +216,8 @@ def run_dlrm(args):
     # builds or calls this function — train_jit above stays the bitwise
     # fp32 path.
     quant_keys = tuple(k for k in ("embed", "wide") if k in params)
-    qres = ({k: jnp.zeros_like(params[k]) for k in quant_keys}
+    qres = (jax.device_put({k: jnp.zeros_like(params[k]) for k in quant_keys},
+                           {k: shardings[k] for k in quant_keys})
             if codec is not None else None)
 
     @partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -258,6 +261,9 @@ def run_dlrm(args):
                                   max_ids=out_rows * wl.width)
         else:
             esd = esd_init(n, V)
+        # the dispatch state is replicated over the mesh, never parked
+        # on the first device
+        esd = jax.device_put(esd, replicated)
 
     start = 0
     if args.resume:
@@ -270,9 +276,10 @@ def run_dlrm(args):
         params = jax.device_put(restored["params"], shardings)
         opt_state = jax.tree.map(jnp.asarray, restored["opt"])
         if use_esd:
-            esd = jax.tree.map(jnp.asarray, restored["esd"])
+            esd = jax.device_put(restored["esd"], replicated)
         if codec is not None:
-            qres = jax.tree.map(jnp.asarray, restored["qres"])
+            qres = jax.device_put(restored["qres"],
+                                  {k: shardings[k] for k in quant_keys})
         if args.verbose:
             log_step({"resumed_from_step": start})
     if start >= args.steps:
@@ -296,6 +303,8 @@ def run_dlrm(args):
         if counts is not None:
             # loud failure on silent row loss: an undersized ragged
             # budget must never truncate the batch unnoticed
+            rec["exchange_overflow"] = int(np.asarray(
+                counts.get("exchange_overflow", 0)))
             raise_on_overflow(counts)
             base_ops = ("miss_pull", "update_push", "evict_push")
             ops = {op: np.asarray(counts[op]) for op in base_ops}
@@ -381,7 +390,9 @@ def run_dlrm(args):
 
     adv_step = count(start)
     if plan is None:
-        pf_plane = (prefetch_init(args.prefetch_slots, cfg.embedding_dim)
+        pf_plane = (jax.device_put(prefetch_init(args.prefetch_slots,
+                                                 cfg.embedding_dim),
+                                   replicated)
                     if use_prefetch else None)
         pf_cands = max(8 * args.prefetch, 256)
         dec_step = count(start)
@@ -419,10 +430,13 @@ def run_dlrm(args):
                 resident = new_state.latest.any(axis=0)
                 with get_tracer().span("prefetch.pull", track="prefetch",
                                        step=i):
+                    # the pull kernel reads the table on one device; a
+                    # table row-sharded over several takes the XLA gather
                     pf_plane, n_pulled = prefetch_step(
                         pf_plane, params["embed"], resident,
                         jnp.asarray(cids), jnp.asarray(cexp), i,
-                        budget=args.prefetch, codec=args.codec)
+                        budget=args.prefetch, codec=args.codec,
+                        use_pallas=n == 1)
                 aux["prefetch_pulled"] = n_pulled
             else:
                 x, new_state, counts = advance_jit(state, s, d, l, assign)
@@ -510,8 +524,8 @@ def run_dlrm(args):
 # --------------------------------------------------------------------------
 def run_lm(args):
     cfg = get_config(args.arch, smoke=args.smoke)
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_host_mesh()
+    n_dev = mesh.shape["data"]
     optimizer = get_optimizer("adam", args.lr)
     params = api.init_model(jax.random.key(args.seed), cfg)
     opt_state = optimizer.init(params)
@@ -680,6 +694,7 @@ def build_parser():
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     trace = args.trace_out is not None or args.validate_timing
     tracer = Tracer(capacity=args.trace_buffer) if trace else None
     prev = set_tracer(tracer) if trace else None

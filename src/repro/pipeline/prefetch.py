@@ -108,11 +108,12 @@ def prefetch_candidates(meta, step: int, max_cands: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("budget", "codec", "interpret"))
+                   static_argnames=("budget", "codec", "use_pallas",
+                                    "interpret"))
 def prefetch_step(plane: PrefetchPlane, table: jnp.ndarray,
                   resident: jnp.ndarray, cand_ids: jnp.ndarray,
                   cand_expiry: jnp.ndarray, step,
-                  *, budget: int, codec=None,
+                  *, budget: int, codec=None, use_pallas: bool = True,
                   interpret: bool | None = None):
     """One prefetch round: stage up to ``budget`` future-miss rows.
 
@@ -126,7 +127,10 @@ def prefetch_step(plane: PrefetchPlane, table: jnp.ndarray,
     (b) resident ids are skipped; (c) the first ``min(budget, free
     slots)`` remaining candidates (candidates arrive urgency-sorted)
     are pulled into expired/empty slots via the fused
-    :func:`staged_gather` kernel.  Returns ``(new_plane, n_pulled)``.
+    :func:`staged_gather` kernel.  ``use_pallas=False`` pulls the same
+    rows with an XLA gather instead — the path for a table sharded over
+    several devices, which a Pallas kernel cannot be partitioned over.
+    Returns ``(new_plane, n_pulled)``.
     """
     C = plane.ids.shape[0]
     P = cand_ids.shape[0]
@@ -170,7 +174,7 @@ def prefetch_step(plane: PrefetchPlane, table: jnp.ndarray,
     new_ids = ids0.at[sel_slot].set(sel_ids, mode="drop")
     new_exp = expiry0.at[sel_slot].set(sel_exp, mode="drop")
     c = get_codec(codec)
-    if c is None:
+    if c is None and use_pallas:
         src = jnp.full((C,), -1, jnp.int32).at[sel_slot].set(
             jnp.clip(sel_ids, 0, V - 1), mode="drop")
         new_rows = staged_gather(plane.rows, table, src,
@@ -179,7 +183,9 @@ def prefetch_step(plane: PrefetchPlane, table: jnp.ndarray,
         # wire-format path: the plane holds what the receiver would
         # reconstruct after the exchange codec (fake_quant = dequantized
         # codes), so staged-row freshness reflects the real transport
-        pulled = fake_quant(table[jnp.clip(sel_ids, 0, V - 1)], c)
+        pulled = table[jnp.clip(sel_ids, 0, V - 1)]
+        if c is not None:
+            pulled = fake_quant(pulled, c)
         new_rows = plane.rows.at[sel_slot].set(
             jnp.where(sel_ok[:, None], pulled, 0.0), mode="drop")
     n_pulled = take.sum().astype(jnp.int32)

@@ -12,12 +12,9 @@ def rng():
 
 # --------------------------------------------------------------------------
 # Test sharding: `--shard I/N` keeps every N-th collected test starting at
-# I (0-based).  Opt-in for CI machines with real parallelism — run the N
-# shards as concurrent pytest processes; round-robin over the collection
-# order interleaves the heavy per-arch parameterizations, and the shards
-# partition the full selection exactly.  (scripts/ci.sh does NOT use it:
-# this 2-vCPU sandbox time-shares one core and concurrent shards measured
-# slower than one sequential run.)
+# I (0-based): run the N shards as concurrent pytest processes;
+# round-robin over the collection order interleaves the heavy per-arch
+# parameterizations, and the shards partition the full selection exactly.
 # --------------------------------------------------------------------------
 def pytest_addoption(parser):
     parser.addoption(

@@ -6,18 +6,20 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
+ROOT = Path(__file__).resolve().parents[1]
+
 
 def _run_py(code, timeout=300):
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+           "HOME": os.environ.get("HOME", str(ROOT)),
            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
-    if "XLA_FLAGS" in os.environ:
-        env["XLA_FLAGS"] = os.environ["XLA_FLAGS"]
     return subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)], capture_output=True,
-        text=True, timeout=timeout, cwd="/root/repo", env=env,
+        text=True, timeout=timeout, cwd=ROOT, env=env,
     )
 
 

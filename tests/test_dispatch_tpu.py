@@ -4,6 +4,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8 (tests themselves must
 keep the default single device)."""
 import os
 import subprocess
+from pathlib import Path
 import sys
 import textwrap
 
@@ -96,11 +97,12 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.dispatch_tpu import esd_dispatch, esd_init, need_matrix
+    from repro.launch.mesh import make_mesh
 
     n, m, F, V = 8, 16, 4, 100
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     rng = np.random.default_rng(0)
     samples = rng.integers(0, V, (n * m, F)).astype(np.int32)
     state = esd_init(n, V)
@@ -114,7 +116,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     exch, assign, need = shard_map(
         f, mesh=mesh, in_specs=(P("data", None),),
         out_specs=(P("data", None), P("data"), P(None, None)),
-        check_rep=False)(jnp.asarray(samples))
+        check_vma=False)(jnp.asarray(samples))
     exch, assign = np.asarray(exch), np.asarray(assign)
 
     # 1) every shard sends exactly m/n to each worker
@@ -148,10 +150,10 @@ def test_shard_map_dispatch_8dev():
         [sys.executable, "-c", MULTIDEV_SCRIPT],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", ""),
              # the script wants 8 *host* devices; keep jax off any real
              # accelerator the machine happens to have
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo",
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert "MULTIDEV_OK" in res.stdout, res.stdout + res.stderr

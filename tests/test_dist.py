@@ -10,12 +10,9 @@ a rank-mismatched constraint.
 import jax
 import numpy as np
 import pytest
-from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, AxisType, NamedSharding, PartitionSpec as P
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import CONFIGS, INPUT_SHAPES, SMOKE_CONFIGS
 from repro.dist import ctx
@@ -58,7 +55,8 @@ def _pairs(shapes, specs):
 def _mock_mesh(data=16, model=16):
     """A 256-device production-shaped mesh with no physical devices —
     lets a single-CPU test validate multi-device placements."""
-    return AbstractMesh((("data", data), ("model", model)))
+    return AbstractMesh((data, model), ("data", "model"),
+                        axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 class TestRoundTrip:

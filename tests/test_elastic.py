@@ -28,6 +28,7 @@ from repro.core.cache import SparseClusterCache
 from repro.core.dispatch_tpu import esd_init, esd_sparse_init
 from repro.core.simulator import SimConfig, simulate
 from repro.data.synthetic import WORKLOADS
+from repro.launch.mesh import make_mesh
 from repro.elastic import (ClusterState, FaultEvent, FaultPlan,
                            cost_column_bias, departure_handoff, effective_t,
                            gap_bound, mask_state, rejoin_handoff,
@@ -419,7 +420,7 @@ class TestRecovery:
         from repro.launch.steps import make_dlrm_esd_stages
         n, m = 1, 16
         cap = int(0.2 * wl.vocab)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         t = jnp.asarray([1e-4], jnp.float32)
         dec, adv, _, rows = make_dlrm_esd_stages(
             mesh, n, m, wl.vocab, t, 1.0, exchange="ragged", capacity=cap)
@@ -484,7 +485,8 @@ def _run_subprocess(script):
     return subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", str(REPO)),
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
         cwd=str(REPO))
 
@@ -500,13 +502,14 @@ from repro.configs import DLRM_CONFIGS
 from repro.core.dispatch_tpu import esd_sparse_init
 from repro.data.synthetic import WORKLOADS
 from repro.elastic import FaultPlan, cost_column_bias, effective_t
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_dlrm_esd_stages
 
 n, m = 4, 16          # m = per-shard rows (batch_per_worker)
 wl = WORKLOADS[DLRM_CONFIGS["wdl-tiny"].workload]
 V = wl.vocab
 capacity = int(0.2 * V)
-mesh = jax.make_mesh((n, 1), ("data", "model"))
+mesh = make_mesh((n, 1), ("data", "model"))
 t_tran = jnp.asarray(np.linspace(1e-4, 4e-4, n), jnp.float32)
 
 def batches(seed, steps):
@@ -626,7 +629,7 @@ class TestDriverGuards:
     def test_elastic_stages_need_ragged(self):
         from repro.launch.steps import make_dlrm_esd_stages
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError, match="ragged"):
             make_dlrm_esd_stages(mesh, 1, 16, 100, jnp.ones((1,)), 0.0,
                                  elastic=True)

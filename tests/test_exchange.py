@@ -16,6 +16,7 @@ Contracts under test:
 """
 import sys
 import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +24,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, "tests")
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import SimConfig, simulate
 from repro.core.dispatch_tpu import (
@@ -37,6 +35,7 @@ from repro.core.dispatch_tpu import (
     hybrid_dispatch_jax,
 )
 from repro.data.synthetic import WORKLOADS
+from repro.launch.mesh import make_mesh
 from repro.exchange import (
     bucket_sizes,
     compact_recv,
@@ -129,15 +128,17 @@ class TestPlan:
 
     def test_schedule_len_bound(self):
         """len(schedule) <= floor(log2(cap)) + 2: all pow2s up to cap
-        plus the terminal cap bucket."""
+        plus the terminal bucket (cap, or the fixed-shape block when that
+        is smaller)."""
         rng = np.random.default_rng(0)
         for cap in (7, 8, 96, 100):
             n, m = 8, cap
             assign = rng.integers(0, n, n * m)
             plan = compile_plan(assign, n, cap=cap)
             assert len(plan.schedule) <= int(np.floor(np.log2(cap))) + 2
+            top = min(cap, plan.padded_block)
             for b in plan.schedule:
-                assert b == cap or (b & (b - 1)) == 0
+                assert b == top or (b & (b - 1)) == 0
 
     def test_skew_pad_reduction(self):
         """Fully skewed: ragged ships zero pad, padded ships ~n x."""
@@ -226,11 +227,11 @@ class TestRaggedExecutor:
 
     def test_n1_shard_map_bitwise(self, rng):
         """n = 1 real shard_map: ragged esd_dispatch == padded bitwise."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         m, F, V = 8, 3, 50
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         samples = jnp.asarray(rng.integers(0, V, (m, F)), jnp.int32)
         state = esd_sparse_init(1, V)
         t = jnp.ones((1,), jnp.float32)
@@ -242,7 +243,7 @@ class TestRaggedExecutor:
                 return out, assign
             return shard_map(f, mesh=mesh, in_specs=(P("data", None),),
                              out_specs=(P("data", None), P("data")),
-                             check_rep=False)(samples)
+                             check_vma=False)(samples)
 
         out_p, a_p = run("padded")
         out_r, a_r = run("ragged")
@@ -355,13 +356,13 @@ class TestPallasPsDegrade:
         """use_pallas + n_ps > 1: no longer raises — one RuntimeWarning,
         then the jnp ps cost matrix result."""
         import repro.core.dispatch_tpu as dt
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.ps import make_partition
 
         V, m, F = 40, 8, 3
         part = make_partition(V, 2)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         samples = jnp.asarray(
             part.to_linear(rng.integers(0, V, (m, F))), jnp.int32)
         state = esd_sparse_init(1, part.linear_size)
@@ -373,7 +374,7 @@ class TestPallasPsDegrade:
                                     use_pallas=use_pallas)
             return shard_map(f, mesh=mesh, in_specs=(P("data", None),),
                              out_specs=(P("data", None), P("data")),
-                             check_rep=False)(samples)
+                             check_vma=False)(samples)
 
         dt._pallas_ps_warned = False
         with warnings.catch_warnings(record=True) as w:
@@ -396,14 +397,15 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.dispatch_tpu import esd_dispatch, esd_sparse_init, \
     dispatch_cap, exchange_budget
 from repro.exchange import gather_reference
 from repro.exchange.ragged import ragged_exchange
+from repro.launch.mesh import make_mesh
 
 n, m, F, V = 8, 16, 4, 100
-mesh = jax.make_mesh((n,), ("data",))
+mesh = make_mesh((n,), ("data",))
 rng = np.random.default_rng(0)
 samples = rng.integers(0, V, (n * m, F)).astype(np.int32)
 state = esd_sparse_init(n, V)
@@ -417,7 +419,7 @@ def run(mode, cap_slack=0.0):
                 else n * exchange_budget(dispatch_cap(m, n, cap_slack), m))
     return shard_map(f, mesh=mesh, in_specs=(P("data", None),),
                      out_specs=(P("data", None), P("data")),
-                     check_rep=False)(jnp.asarray(samples))
+                     check_vma=False)(jnp.asarray(samples))
 
 # 1) hard cap: ragged is bitwise-equal to padded on the real collective
 out_p, a_p = run("padded")
@@ -448,7 +450,7 @@ def g(s, a):
 out_k, tot, rc = shard_map(
     g, mesh=mesh, in_specs=(P("data", None), P("data")),
     out_specs=(P("data", None), P("data"), P("data", None)),
-    check_rep=False)(jnp.asarray(samples), jnp.asarray(skew))
+    check_vma=False)(jnp.asarray(samples), jnp.asarray(skew))
 tot = np.asarray(tot)
 assert tot[0] == n * m and (tot[1:] == 0).all(), tot
 np.testing.assert_array_equal(
@@ -465,9 +467,10 @@ def test_shard_map_ragged_8dev():
     res = subprocess.run(
         [sys.executable, "-c", MULTIDEV_SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")},
-        cwd="/root/repo",
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert "MULTIDEV_EXCHANGE_OK" in res.stdout, res.stdout + res.stderr
 
@@ -476,7 +479,7 @@ class TestExchangeSpecs:
     def test_specs_shapes(self):
         from repro.dist.sharding import exchange_specs
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         specs = exchange_specs(mesh)
         assert len(specs["send"]) == 4 and specs["send"][0] is not None
         assert len(specs["counts"]) == 2

@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import heu_dispatch, hungarian_dispatch, hybrid_dispatch, min2_minus_min
 

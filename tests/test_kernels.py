@@ -1,18 +1,20 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import cost_matrix_np, hungarian_dispatch
 from repro.kernels import auction_solve_pallas, cost_matrix_pallas
 from repro.kernels.auction import auction_bids
-from repro.kernels.emb_lookup import pooled_lookup
-from repro.kernels.ref import auction_bids_ref, pooled_lookup_ref
+from repro.kernels.emb_lookup import (pooled_lookup, pooled_lookup_quant,
+                                      pooled_lookup_staged, staged_gather)
+from repro.kernels.exchange_pack import gather_rows_pallas
+from repro.kernels.ref import (auction_bids_ref, gather_rows_ref,
+                               pooled_lookup_quant_ref, pooled_lookup_ref,
+                               pooled_lookup_staged_ref, staged_gather_ref)
 
 
 class TestPooledLookup:
@@ -104,3 +106,76 @@ class TestCostMatrixKernel:
         got = cost_matrix_pallas(jnp.asarray(samples), jnp.asarray(latest),
                                  jnp.asarray(dirty), jnp.asarray(t))
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
+class TestRefBitwise:
+    """Every main-path kernel against its kernels/ref.py oracle, bitwise,
+    in interpret mode.  Row counts off the 8-row tile exercise the
+    partial-tail DMA path; weights are 0/1 masks (the cost-matrix and
+    pooled-history uses), whose products are exact — with fractional
+    weights the interpreted kernel's fused multiply-add rounds once
+    where the reference rounds twice (TestPooledLookup covers those to
+    tolerance)."""
+
+    SHAPES = [(3, 5, 6, 16), (8, 26, 1003, 512), (13, 7, 64, 130)]
+
+    @pytest.mark.parametrize("block_f", [None, 3])
+    @pytest.mark.parametrize("B,F,V,E", SHAPES)
+    def test_pooled_lookup(self, rng, B, F, V, E, block_f):
+        table = jnp.asarray(rng.standard_normal((V, E)), jnp.float32)
+        ids = jnp.asarray(rng.integers(-1, V, (B, F)), jnp.int32)
+        w = jnp.asarray(rng.random((B, F)) < 0.7, jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(pooled_lookup(table, ids, w, block_f=block_f)),
+            np.asarray(pooled_lookup_ref(table, ids, w)))
+
+    @pytest.mark.parametrize("B,F,V,E", SHAPES)
+    def test_pooled_lookup_staged(self, rng, B, F, V, E):
+        C = max(V // 3, 1)
+        table = jnp.asarray(rng.standard_normal((V, E)), jnp.float32)
+        plane = jnp.asarray(rng.standard_normal((C, E)), jnp.float32)
+        ids = rng.integers(-1, V, (B, F))
+        slots = np.where(rng.random((B, F)) < 0.5,
+                         rng.integers(0, C, (B, F)), -1)
+        slots[ids < 0] = -1
+        ids, slots = jnp.asarray(ids, jnp.int32), jnp.asarray(slots, jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(pooled_lookup_staged(plane, table, slots, ids)),
+            np.asarray(pooled_lookup_staged_ref(plane, table, slots, ids)))
+
+    @pytest.mark.parametrize("C,V,E", [(5, 6, 16), (21, 1003, 512),
+                                       (64, 13, 130)])
+    def test_staged_gather(self, rng, C, V, E):
+        table = jnp.asarray(rng.standard_normal((V, E)), jnp.float32)
+        plane = jnp.asarray(rng.standard_normal((C, E)), jnp.float32)
+        src = jnp.asarray(np.where(rng.random(C) < 0.5,
+                                   rng.integers(0, V, C), -1), jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(staged_gather(plane, table, src)),
+            np.asarray(staged_gather_ref(plane, table, src)))
+
+    @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+    @pytest.mark.parametrize("m,F,S", [(5, 3, 7), (130, 74, 128),
+                                       (16, 512, 19)])
+    def test_gather_rows(self, rng, m, F, S, dtype):
+        rows = jnp.asarray(rng.integers(0, 999, (m, F)), dtype)
+        idx = jnp.asarray(np.where(rng.random(S) < 0.7,
+                                   rng.integers(0, m, S), -1), jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(gather_rows_pallas(rows, idx)),
+            np.asarray(gather_rows_ref(rows, idx)))
+
+    @pytest.mark.parametrize("codec", ["int8", "int8:32", "int4:7"])
+    def test_pooled_lookup_quant(self, rng, codec):
+        from repro.quant.codecs import quantize_rows
+
+        V, E, B, F = 1003, 512, 11, 9
+        codes, scale, zp = quantize_rows(
+            jnp.asarray(rng.standard_normal((V, E)), jnp.float32), codec)
+        ids = jnp.asarray(rng.integers(-1, V, (B, F)), jnp.int32)
+        got = pooled_lookup_quant(codes, scale, zp, ids, codec=codec)
+        # compiled like the interpreted kernel, so the dequantize
+        # multiply-add contracts to one FMA on both sides
+        want = jax.jit(pooled_lookup_quant_ref, static_argnums=4)(
+            codes, scale, zp, ids, codec)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

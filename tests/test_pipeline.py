@@ -30,10 +30,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, "tests")
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import DLRM_CONFIGS
 from repro.core.cost import (cost_matrix_sparse, cost_matrix_sparse_ps,
@@ -43,6 +40,7 @@ from repro.core.simulator import (DEFAULT_BANDWIDTHS, SimConfig,
                                   calibrated_decision_time,
                                   exchange_worker_times, simulate)
 from repro.data.synthetic import WORKLOADS, CTRWorkload
+from repro.launch.mesh import make_mesh
 from repro.models import dlrm
 from repro.pipeline import (LookaheadWindow, PipelinedRunner, changed_ids,
                             db_commit, db_init, staleness_bound,
@@ -302,7 +300,7 @@ def _run_stage_pipeline(depth, steps=5, lookahead=0, stale=False,
 
     cfg = DLRM_CONFIGS["wdl-tiny"]
     wl = WORKLOADS[cfg.workload]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     n, m = 1, 16
     V = wl.vocab
     capacity = int(0.2 * V)
@@ -434,7 +432,7 @@ class TestBitwiseEquivalence:
                   "--batch-per-worker", "8", "--pipeline-depth", "2"])
         # the stage factory enforces the same slack/exchange rule as
         # esd_dispatch (padded cannot carry a relaxed capacity)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError):
             make_dlrm_esd_stages(mesh, 1, 16, 100, jnp.ones((1,)), 0.0,
                                  exchange="padded", cap_slack=0.5)

@@ -33,10 +33,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, "tests")
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cache import ClusterCache, EvictPlan, SparseClusterCache
 from repro.core.dispatch_tpu import esd_reassign

@@ -25,10 +25,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, "tests")
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     ClusterCache,
@@ -47,6 +44,7 @@ from repro.core.dispatch_tpu import (
     need_ids_local,
 )
 from repro.data.synthetic import WORKLOADS
+from repro.launch.mesh import make_mesh
 from repro.ps import PsPartition, make_partition
 
 
@@ -411,7 +409,7 @@ class TestPsModelAndSharding:
         tree = {"embed": jax.ShapeDtypeStruct((4, 25, 8), jnp.float32),
                 "wide": jax.ShapeDtypeStruct((4, 25, 1), jnp.float32),
                 "top": [{"w": jax.ShapeDtypeStruct((8, 1), jnp.float32)}]}
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         specs = param_specs(tree, mesh=mesh)
         assert specs["embed"] == P("data", None, None)
         assert specs["wide"] == P("data", None, None)

@@ -21,6 +21,7 @@ Contracts under test:
 """
 import json
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +29,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, "tests")
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cost import transmission_time, transmission_time_codec
 from repro.quant import (
@@ -282,15 +280,19 @@ class TestDriver:
         import os
         import subprocess
 
-        env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        root = Path(__file__).resolve().parents[1]
+        # XLA_FLAGS stays behind: importing repro.launch.dryrun in this
+        # worker rewrites it to 512 host devices
+        env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+               "HOME": os.environ.get("HOME", str(root)),
                "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
-        for var in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR",
+        for var in ("JAX_COMPILATION_CACHE_DIR",
                     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
             if var in os.environ:
                 env[var] = os.environ[var]
         return subprocess.run(
             [sys.executable, "-m"] + argv, capture_output=True, text=True,
-            timeout=timeout, cwd="/root/repo", env=env)
+            timeout=timeout, cwd=root, env=env)
 
     def test_codec_none_matches_default(self):
         """--codec none is the bitwise default path (the quant branch is

@@ -32,6 +32,7 @@ import pytest
 from repro.configs import DLRM_CONFIGS
 from repro.core.simulator import SimConfig
 from repro.data.synthetic import WORKLOADS
+from repro.launch.mesh import make_mesh
 from repro.models import dlrm
 from repro.pipeline.prefetch import PrefetchPlane, slot_map
 from repro.serve import (MicroBatch, ServeKnobs, StreamConfig,
@@ -343,7 +344,7 @@ class TestMixedTenancy:
         wl = WORKLOADS[cfg.workload]
         n, m, steps = 1, 16, 4
         cap = int(0.2 * wl.vocab)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         t = jnp.asarray([1e-4], jnp.float32)
         dec, adv, _, rows = make_dlrm_esd_stages(
             mesh, n, m, wl.vocab, t, 1.0, exchange="ragged", capacity=cap)

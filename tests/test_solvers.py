@@ -4,10 +4,7 @@ import itertools
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container has no hypothesis
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     assignment_cost,

@@ -4,26 +4,28 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+ROOT = Path(__file__).resolve().parents[1]
+
 
 def _run(argv, timeout=900):
-    # Hermetic env, except the jax platform/compiler selection: tier-1 is
-    # a CPU suite (see conftest), and dropping JAX_PLATFORMS on a TPU host
-    # makes the subprocess initialize the TPU driver instead of running
-    # the test.  XLA_FLAGS rides along so ci.sh's compile-speed flags
-    # reach the driver subprocesses too.
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+    # Hermetic env, except the jax platform and compile-cache selection:
+    # tier-1 is a CPU suite.  XLA_FLAGS stays behind: importing
+    # repro.launch.dryrun in this worker rewrites it to 512 host devices.
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+           "HOME": os.environ.get("HOME", str(ROOT)),
            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
-    for var in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR",
+    for var in ("JAX_COMPILATION_CACHE_DIR",
                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
         if var in os.environ:
             env[var] = os.environ[var]
     return subprocess.run(
         [sys.executable, "-m"] + argv, capture_output=True, text=True,
-        timeout=timeout, cwd="/root/repo", env=env,
+        timeout=timeout, cwd=ROOT, env=env,
     )
 
 
