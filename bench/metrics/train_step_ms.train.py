@@ -1,0 +1,13 @@
+"""Device milliseconds per training step of the train step: forward,
+backward and row-wise Adagrad over the table and the MLPs."""
+
+MODULES = ("jit_train_jit",)
+STEP = "jit_train_jit"
+
+
+def read(ctx):
+    red = ctx["reduced"]
+    steps = red.module_calls.get(STEP, 0)
+    if not steps or not any(m in red.module_s for m in MODULES):
+        return None
+    return 1e3 * red.seconds(MODULES) / steps
