@@ -433,170 +433,181 @@ def esd_state_update_sparse(state: SparseEsdState, need_ids: jnp.ndarray,
     step = state.step + 1
     valid = need_ids >= 0
 
-    # touched-id universe: sorted unique over all workers, pad sentinel V
-    flat = jnp.where(valid, need_ids, V).reshape(-1)
-    uids = jnp.unique(flat, size=n * L, fill_value=V)          # (U,) sorted
-    uvalid = uids < V
-    g = jnp.minimum(uids, V - 1)                               # safe gather col
-    rows = jnp.arange(n)[:, None]
+    with jax.named_scope("universe"):
+        # touched-id universe: sorted unique over all workers, pad sentinel V
+        flat = jnp.where(valid, need_ids, V).reshape(-1)
+        uids = jnp.unique(flat, size=n * L, fill_value=V)      # (U,) sorted
+        uvalid = uids < V
+        g = jnp.minimum(uids, V - 1)                   # safe gather col
+        rows = jnp.arange(n)[:, None]
 
-    # need membership on the compact universe
-    pos = jnp.searchsorted(uids, jnp.where(valid, need_ids, V))
-    needU = (jnp.zeros((n, uids.shape[0]), jnp.int32)
-             .at[rows, pos].add(valid.astype(jnp.int32), mode="drop")) > 0
+        # need membership on the compact universe
+        pos = jnp.searchsorted(uids, jnp.where(valid, need_ids, V))
+        needU = (jnp.zeros((n, uids.shape[0]), jnp.int32)
+                 .at[rows, pos].add(valid.astype(jnp.int32), mode="drop")) > 0
 
-    latU = state.latest[:, g] & uvalid[None, :]
-    dirU = state.dirty[:, g] & uvalid[None, :]
-    lastU = state.last_access[:, g]
+    with jax.named_scope("phases"):
+        latU = state.latest[:, g] & uvalid[None, :]
+        dirU = state.dirty[:, g] & uvalid[None, :]
+        lastU = state.last_access[:, g]
 
-    # Phase A: on-demand update push
-    need_anyU = needU.any(axis=0)
-    sole = needU & (needU.sum(axis=0) == 1)[None, :]
-    need_other = need_anyU[None, :] & ~sole
-    pushers = dirU & need_other
-    update_push = pushers.sum(axis=1)
-    pushed = pushers.any(axis=0)
-    multi = pushers.sum(axis=0) > 1
-    latU = latU & ~(pushed[None, :] & ~pushers) & ~multi[None, :]
-    dirU = dirU & ~pushers
+        # Phase A: on-demand update push
+        need_anyU = needU.any(axis=0)
+        sole = needU & (needU.sum(axis=0) == 1)[None, :]
+        need_other = need_anyU[None, :] & ~sole
+        pushers = dirU & need_other
+        update_push = pushers.sum(axis=1)
+        pushed = pushers.any(axis=0)
+        multi = pushers.sum(axis=0) > 1
+        latU = latU & ~(pushed[None, :] & ~pushers) & ~multi[None, :]
+        dirU = dirU & ~pushers
 
-    # Phase B: miss pull
-    miss = needU & ~latU
-    miss_pull = miss.sum(axis=1)
-    latU = latU | needU
+        # Phase B: miss pull
+        miss = needU & ~latU
+        miss_pull = miss.sum(axis=1)
+        latU = latU | needU
 
-    # Phase C: train
-    dirU = dirU | needU
-    latU = latU & ~(need_anyU[None, :] & ~needU)
-    lastU = jnp.where(needU, step, lastU)
+        # Phase C: train
+        dirU = dirU | needU
+        latU = latU & ~(need_anyU[None, :] & ~needU)
+        lastU = jnp.where(needU, step, lastU)
 
-    # scatter the touched columns back; pad columns are routed out of
-    # bounds and dropped so they can never alias a real column's write
-    gs = jnp.where(uvalid, uids, V)
-    latest = state.latest.at[:, gs].set(latU, mode="drop")
-    dirty = state.dirty.at[:, gs].set(dirU, mode="drop")
-    last_access = state.last_access.at[:, gs].set(lastU, mode="drop")
+        # scatter the touched columns back; pad columns are routed out of
+        # bounds and dropped so they can never alias a real column's write
+        gs = jnp.where(uvalid, uids, V)
+        latest = state.latest.at[:, gs].set(latU, mode="drop")
+        dirty = state.dirty.at[:, gs].set(dirU, mode="drop")
+        last_access = state.last_access.at[:, gs].set(lastU, mode="drop")
 
-    # optional LRU capacity: strict cut over the bounded candidate set
-    # (previous survivors + this step's ids), identical to the dense
-    # full-vocab top_k because every id outside the candidate set has a
-    # strictly smaller recency key than every id inside it.
-    #
-    # One ascending sort of the candidate keys does all the work: pinned
-    # ids (just stamped last_access = step) hold the globally largest
-    # keys, so the kept set is a contiguous suffix of the sorted keys and
-    # the evicted candidates (at most 2L of them) sit in a contiguous
-    # zone right below the top-capacity block — no argsort, no
-    # candidate-wide scatters.
-    evict_push = jnp.zeros((n,), jnp.int32)
-    evict_push_ps = (jnp.zeros((n, part.n_ps), jnp.int32)
-                     if part is not None else None)
-    slots = state.slots
-    if capacity_ps is not None:
-        # per-PS budgets: the identical strict cut, run once per shard
-        # over that shard's candidates (its slot segment + this step's
-        # ids homed there), each against its own capacity[p]
-        offs = np.cumsum([0] + [c + L for c in capacity_ps])
-        if slots.shape[1] < offs[-1]:
-            raise ValueError(
-                f"slot buffer {slots.shape[1]} < sum(cap_p + L) = {offs[-1]}; "
-                "init the state with esd_sparse_init(..., capacity_ps, "
-                "max_ids=L)")
-        imax = jnp.iinfo(jnp.int32).max
-        shard_need = part.shard_of_linear(jnp.where(valid, need_ids, 0))
-        new_segs, ev_counts = [], []
-        for p, cap_p in enumerate(capacity_ps):
-            valid_p = valid & (shard_need == p)
-            need_p = jnp.where(valid_p, need_ids, -1)
-            slots_p = state.slots[:, offs[p]:offs[p] + cap_p + L]
-            need_sorted = jnp.sort(jnp.where(valid_p, need_ids, imax), axis=1)
+    with jax.named_scope("capacity_cut"):
+        # optional LRU capacity: strict cut over the bounded candidate set
+        # (previous survivors + this step's ids), identical to the dense
+        # full-vocab top_k because every id outside the candidate set has a
+        # strictly smaller recency key than every id inside it.
+        #
+        # One ascending sort of the candidate keys does all the work: pinned
+        # ids (just stamped last_access = step) hold the globally largest
+        # keys, so the kept set is a contiguous suffix of the sorted keys and
+        # the evicted candidates (at most 2L of them) sit in a contiguous
+        # zone right below the top-capacity block — no argsort, no
+        # candidate-wide scatters.
+        evict_push = jnp.zeros((n,), jnp.int32)
+        evict_push_ps = (jnp.zeros((n, part.n_ps), jnp.int32)
+                         if part is not None else None)
+        slots = state.slots
+        if capacity_ps is not None:
+            # per-PS budgets: the identical strict cut, run once per shard
+            # over that shard's candidates (its slot segment + this step's
+            # ids homed there), each against its own capacity[p]
+            offs = np.cumsum([0] + [c + L for c in capacity_ps])
+            if slots.shape[1] < offs[-1]:
+                raise ValueError(
+                    f"slot buffer {slots.shape[1]} < sum(cap_p + L) = "
+                    f"{offs[-1]}; "
+                    "init the state with esd_sparse_init(..., capacity_ps, "
+                    "max_ids=L)")
+            imax = jnp.iinfo(jnp.int32).max
+            shard_need = part.shard_of_linear(jnp.where(valid, need_ids, 0))
+            new_segs, ev_counts = [], []
+            for p, cap_p in enumerate(capacity_ps):
+                valid_p = valid & (shard_need == p)
+                need_p = jnp.where(valid_p, need_ids, -1)
+                slots_p = state.slots[:, offs[p]:offs[p] + cap_p + L]
+                need_sorted = jnp.sort(jnp.where(valid_p, need_ids, imax),
+                                       axis=1)
+                hit = jnp.take_along_axis(
+                    need_sorted,
+                    jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots_p),
+                             0, L - 1),
+                    axis=1)
+                slot_cand = jnp.where((hit == slots_p) & (slots_p >= 0), -1,
+                                      slots_p)
+                cand = jnp.concatenate([need_p, slot_cand], axis=1)
+                gc = jnp.clip(cand, 0, V - 1)
+                la_c = jnp.where(cand >= 0, last_access[rows, gc], -1)
+                sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
+                T_p = cand.shape[1]                      # = cap_p + 2L
+                zone = slice(T_p - cap_p - 2 * L, T_p - cap_p)
+                ev = (sla[:, zone] >= 0) & (sla[:, zone] < step)
+                ev_ids = jnp.where(ev, sid[:, zone], V)
+                egc = jnp.minimum(ev_ids, V - 1)
+                lat_e = latest[rows, egc] & ev
+                dr_e = dirty[rows, egc] & ev
+                ev_counts.append((lat_e & dr_e).sum(axis=1).astype(jnp.int32))
+                latest = latest.at[rows, ev_ids].set(False, mode="drop")
+                dirty = dirty.at[rows, ev_ids].set(False, mode="drop")
+                S_p = cap_p + L
+                top_la, top_id = sla[:, T_p - S_p:], sid[:, T_p - S_p:]
+                keepm = (top_la >= 0) & (
+                    (jnp.arange(S_p) >= S_p - cap_p)[None, :]
+                    | (top_la == step))
+                new_segs.append(jnp.where(keepm, top_id, -1))
+            evict_push = sum(ev_counts)
+            # part is never None here
+            evict_push_ps = jnp.stack(ev_counts, axis=1)
+            slots = jnp.concatenate(new_segs, axis=1)
+            if slots.shape[1] < state.slots.shape[1]:
+                slots = jnp.concatenate(
+                    [slots, jnp.full((n, state.slots.shape[1]
+                                      - slots.shape[1]),
+                                     -1, jnp.int32)], axis=1)
+        elif capacity is not None and capacity < V:
+            if slots.shape[1] < capacity + L:
+                raise ValueError(
+                    f"slot buffer {slots.shape[1]} < capacity+L = "
+                    f"{capacity + L}; init the state with "
+                    "esd_sparse_init(..., capacity, max_ids=L)")
+            S = slots.shape[1]
+            # candidates: this step's ids (pinned) + previous survivors with
+            # duplicates of this step's ids masked out
+            imax = jnp.iinfo(jnp.int32).max
+            need_sorted = jnp.sort(jnp.where(valid, need_ids, imax), axis=1)
             hit = jnp.take_along_axis(
                 need_sorted,
-                jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots_p),
-                         0, L - 1),
+                jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots), 0,
+                         L - 1),
                 axis=1)
-            slot_cand = jnp.where((hit == slots_p) & (slots_p >= 0), -1,
-                                  slots_p)
-            cand = jnp.concatenate([need_p, slot_cand], axis=1)
+            slot_cand = jnp.where((hit == slots) & (slots >= 0), -1, slots)
+            cand = jnp.concatenate(
+                [jnp.where(valid, need_ids, -1), slot_cand], axis=1)   # (n, T)
+            cvalid = cand >= 0
             gc = jnp.clip(cand, 0, V - 1)
-            la_c = jnp.where(cand >= 0, last_access[rows, gc], -1)
+            # two-key lexicographic sort on (last_access, id): same strict
+            # order as the dense engine's cut without the int32 overflow a
+            # packed la*V + id key would hit at paper scale (x64 disabled).
+            # Invalid candidates get la = -1 so they sort below every valid
+            # one (valid la >= 0).
+            la_c = jnp.where(cvalid, last_access[rows, gc], -1)
             sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
-            T_p = cand.shape[1]                      # = cap_p + 2L
-            zone = slice(T_p - cap_p - 2 * L, T_p - cap_p)
+            T = cand.shape[1]
+
+            # evicted zone: valid, non-pinned entries directly below the
+            # top-capacity block (never more than 2L evictions per step)
+            zone = slice(T - capacity - 2 * L, T - capacity)
+            # pinned: la == step; V: drop
             ev = (sla[:, zone] >= 0) & (sla[:, zone] < step)
             ev_ids = jnp.where(ev, sid[:, zone], V)
             egc = jnp.minimum(ev_ids, V - 1)
             lat_e = latest[rows, egc] & ev
             dr_e = dirty[rows, egc] & ev
-            ev_counts.append((lat_e & dr_e).sum(axis=1).astype(jnp.int32))
+            evict_push = (lat_e & dr_e).sum(axis=1).astype(jnp.int32)
+            if part is not None:
+                # non-evicted slots (shard of the sentinel V is out of range
+                # for n_ps > 1) are already masked out by lat_e/dr_e
+                shard_e = part.shard_of_linear(ev_ids)
+                evict_push_ps = ((lat_e & dr_e)[:, :, None]
+                                 & (shard_e[:, :, None]
+                                    == jnp.arange(part.n_ps)[None, None, :])
+                                 ).sum(axis=1).astype(jnp.int32)
             latest = latest.at[rows, ev_ids].set(False, mode="drop")
             dirty = dirty.at[rows, ev_ids].set(False, mode="drop")
-            S_p = cap_p + L
-            top_la, top_id = sla[:, T_p - S_p:], sid[:, T_p - S_p:]
-            keepm = (top_la >= 0) & ((jnp.arange(S_p) >= S_p - cap_p)[None, :]
+
+            # new slots: the kept suffix = top-capacity block plus any pinned
+            # spill right below it (only when a batch exceeds capacity)
+            top_la, top_id = sla[:, T - S:], sid[:, T - S:]            # (n, S)
+            keepm = (top_la >= 0) & ((jnp.arange(S) >= S - capacity)[None, :]
                                      | (top_la == step))
-            new_segs.append(jnp.where(keepm, top_id, -1))
-        evict_push = sum(ev_counts)
-        evict_push_ps = jnp.stack(ev_counts, axis=1)   # part is never None here
-        slots = jnp.concatenate(new_segs, axis=1)
-        if slots.shape[1] < state.slots.shape[1]:
-            slots = jnp.concatenate(
-                [slots, jnp.full((n, state.slots.shape[1] - slots.shape[1]),
-                                 -1, jnp.int32)], axis=1)
-    elif capacity is not None and capacity < V:
-        if slots.shape[1] < capacity + L:
-            raise ValueError(
-                f"slot buffer {slots.shape[1]} < capacity+L = {capacity + L}; "
-                "init the state with esd_sparse_init(..., capacity, max_ids=L)")
-        S = slots.shape[1]
-        # candidates: this step's ids (pinned) + previous survivors with
-        # duplicates of this step's ids masked out
-        imax = jnp.iinfo(jnp.int32).max
-        need_sorted = jnp.sort(jnp.where(valid, need_ids, imax), axis=1)
-        hit = jnp.take_along_axis(
-            need_sorted,
-            jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots), 0, L - 1),
-            axis=1)
-        slot_cand = jnp.where((hit == slots) & (slots >= 0), -1, slots)
-        cand = jnp.concatenate(
-            [jnp.where(valid, need_ids, -1), slot_cand], axis=1)   # (n, T)
-        cvalid = cand >= 0
-        gc = jnp.clip(cand, 0, V - 1)
-        # two-key lexicographic sort on (last_access, id): same strict
-        # order as the dense engine's cut without the int32 overflow a
-        # packed la*V + id key would hit at paper scale (x64 disabled).
-        # Invalid candidates get la = -1 so they sort below every valid
-        # one (valid la >= 0).
-        la_c = jnp.where(cvalid, last_access[rows, gc], -1)
-        sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
-        T = cand.shape[1]
-
-        # evicted zone: valid, non-pinned entries directly below the
-        # top-capacity block (never more than 2L evictions per step)
-        zone = slice(T - capacity - 2 * L, T - capacity)
-        ev = (sla[:, zone] >= 0) & (sla[:, zone] < step)   # pinned: la==step
-        ev_ids = jnp.where(ev, sid[:, zone], V)                    # V: drop
-        egc = jnp.minimum(ev_ids, V - 1)
-        lat_e = latest[rows, egc] & ev
-        dr_e = dirty[rows, egc] & ev
-        evict_push = (lat_e & dr_e).sum(axis=1).astype(jnp.int32)
-        if part is not None:
-            # non-evicted slots (shard of the sentinel V is out of range
-            # for n_ps > 1) are already masked out by lat_e/dr_e
-            shard_e = part.shard_of_linear(ev_ids)
-            evict_push_ps = ((lat_e & dr_e)[:, :, None]
-                             & (shard_e[:, :, None]
-                                == jnp.arange(part.n_ps)[None, None, :])
-                             ).sum(axis=1).astype(jnp.int32)
-        latest = latest.at[rows, ev_ids].set(False, mode="drop")
-        dirty = dirty.at[rows, ev_ids].set(False, mode="drop")
-
-        # new slots: the kept suffix = top-capacity block plus any pinned
-        # spill right below it (only when a batch exceeds capacity)
-        top_la, top_id = sla[:, T - S:], sid[:, T - S:]            # (n, S)
-        keepm = (top_la >= 0) & ((jnp.arange(S) >= S - capacity)[None, :]
-                                 | (top_la == step))
-        slots = jnp.where(keepm, top_id, -1)
+            slots = jnp.where(keepm, top_id, -1)
 
     new = SparseEsdState(latest, dirty, last_access, slots, step)
     counts = {"miss_pull": miss_pull, "update_push": update_push,

@@ -120,6 +120,34 @@ def raise_on_overflow(counts: dict) -> None:
             f"or fix the assignment)")
 
 
+def make_dlrm_train_jit(cfg, optimizer: Optimizer, loss_fn, part=None):
+    """The jitted DLRM train step
+
+      train_jit(params, opt_state, sparse, dense, labels)
+          -> (params, opt_state, loss)
+
+    with the parameters and optimizer state donated.  ``part`` (plain
+    training on a multi-PS table) maps raw ids into the PS-linearized
+    space first.  Its device time carries stable scope names:
+    ``dlrm.train_step`` over ``dlrm.forward`` (the loss; its transposes
+    are the backward) and ``optim.update``.
+    """
+    forward = jax.named_scope("dlrm.forward")(loss_fn)
+
+    @partial(jax.jit, donate_argnums=(0, 1))
+    @jax.named_scope("dlrm.train_step")
+    def train_jit(params, opt_state, sparse, dense, labels):
+        if part is not None:
+            sparse = part.to_linear(sparse)
+        loss, grads = jax.value_and_grad(forward)(params, cfg, sparse, dense,
+                                                  labels)
+        with jax.named_scope("optim.update"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
+        return params, opt_state, loss
+
+    return train_jit
+
+
 def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
                          alpha: float, *, part=None, exchange: str = "padded",
                          cap_slack: float = 0.0, sparse_esd: bool = True,
@@ -216,6 +244,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return assign, jax.lax.psum(alg1, axis)
 
     @jax.jit
+    @jax.named_scope("esd.decide")
     def decide(esd_state, sparse):
         return shard_map(
             lambda s: decide_shard(esd_state, s), mesh=mesh,
@@ -234,24 +263,30 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
                 else need_matrix(s2, axis, V_space))
         return s2, d2, l2, need, overflow
 
+    def exchange_and_need(sparse, dense, labels, assign):
+        with jax.named_scope("esd.exchange"):
+            return shard_map(
+                advance_shard, mesh=mesh,
+                in_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
+                out_specs=(P(axis, None), P(axis, None), P(axis),
+                           P(None, None), P()),
+                check_vma=False)(sparse, dense, labels, assign)
+
     @jax.jit
+    @jax.named_scope("esd.advance")
     def advance(esd_state, sparse, dense, labels, assign, staged=None):
         # staged: optional (V,) bool prefetch-plane membership — splits
         # the step's miss count into prefetch hits vs demand misses
         # (pure accounting; None leaves the update bitwise unchanged)
-        s2, d2, l2, need, overflow = shard_map(
-            advance_shard, mesh=mesh,
-            in_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
-            out_specs=(P(axis, None), P(axis, None), P(axis), P(None, None),
-                       P()),
-            check_vma=False)(sparse, dense, labels, assign)
-        if sparse_esd:
-            new_state, counts = esd_state_update_sparse(esd_state, need,
-                                                        capacity, part,
-                                                        staged=staged)
-        else:
-            new_state, counts = esd_state_update(esd_state, need, capacity,
-                                                 staged=staged)
+        s2, d2, l2, need, overflow = exchange_and_need(sparse, dense, labels,
+                                                       assign)
+        with jax.named_scope("esd.cache_update"):
+            if sparse_esd:
+                new_state, counts = esd_state_update_sparse(
+                    esd_state, need, capacity, part, staged=staged)
+            else:
+                new_state, counts = esd_state_update(esd_state, need,
+                                                     capacity, staged=staged)
         counts = dict(counts)
         counts["exchange_overflow"] = overflow
         return (s2, d2, l2), new_state, counts
@@ -287,6 +322,7 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
         return assign, jax.lax.psum(alg1, axis)
 
     @jax.jit
+    @jax.named_scope("esd.decide")
     def decide_e(esd_state, sparse, t_arr, col_bias, active):
         state = mask_state(esd_state, active)
         return shard_map(
@@ -295,21 +331,19 @@ def make_dlrm_esd_stages(mesh, n: int, m: int, V_space: int, t_tran,
             check_vma=False)(sparse)
 
     @jax.jit
+    @jax.named_scope("esd.advance")
     def advance_e(esd_state, sparse, dense, labels, assign, active):
-        s2, d2, l2, need, overflow = shard_map(
-            advance_shard, mesh=mesh,
-            in_specs=(P(axis, None), P(axis, None), P(axis), P(axis)),
-            out_specs=(P(axis, None), P(axis, None), P(axis), P(None, None),
-                       P()),
-            check_vma=False)(sparse, dense, labels, assign)
-        # mask BEFORE the update: a dead worker's stale planes must not
-        # survive into the committed state (its rejoin is cold)
-        state = mask_state(esd_state, active)
-        if sparse_esd:
-            new_state, counts = esd_state_update_sparse(state, need,
-                                                        capacity, part)
-        else:
-            new_state, counts = esd_state_update(state, need, capacity)
+        s2, d2, l2, need, overflow = exchange_and_need(sparse, dense, labels,
+                                                       assign)
+        with jax.named_scope("esd.cache_update"):
+            # mask BEFORE the update: a dead worker's stale planes must
+            # not survive into the committed state (its rejoin is cold)
+            state = mask_state(esd_state, active)
+            if sparse_esd:
+                new_state, counts = esd_state_update_sparse(state, need,
+                                                            capacity, part)
+            else:
+                new_state, counts = esd_state_update(state, need, capacity)
         counts = dict(counts)
         counts["exchange_overflow"] = overflow
         return (s2, d2, l2), new_state, counts

@@ -54,13 +54,14 @@ from ..data.loader import PrefetchLoader
 from ..data.synthetic import WORKLOADS, token_stream
 from ..dist.sharding import param_specs, to_shardings
 from ..elastic import FaultPlan, cost_column_bias, effective_t
-from ..obs import (MetricsRegistry, Tracer, format_report, get_tracer,
-                   log_step, set_registry, set_tracer, validate_timing)
+from ..obs import (MetricsRegistry, Tracer, get_tracer, log_step,
+                   set_registry, set_tracer)
 from ..pipeline import (LookaheadWindow, PipelinedRunner, prefetch_candidates,
                         prefetch_init, prefetch_step, staged_membership)
 from .cache import use_compile_cache
 from .mesh import make_host_mesh
-from .steps import make_dlrm_esd_stages, make_dlrm_repair_stage
+from .steps import (make_dlrm_esd_stages, make_dlrm_repair_stage,
+                    make_dlrm_train_jit)
 from ..models import api, dlrm
 from ..optim import get_optimizer
 from ..ps import make_partition
@@ -198,14 +199,8 @@ def run_dlrm(args):
                if args.cap_slack > 0.0 or plan is not None
                else dlrm.bce_loss)
 
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def train_jit(params, opt_state, sparse, dense, labels):
-        if not use_esd and part is not None:
-            sparse = part.to_linear(sparse)
-        loss, grads = jax.value_and_grad(loss_fn)(
-            params, cfg, sparse, dense, labels)
-        params, opt_state = optimizer.update(grads, opt_state, params)
-        return params, opt_state, loss
+    train_jit = make_dlrm_train_jit(cfg, optimizer, loss_fn,
+                                    part=None if use_esd else part)
 
     # quantized PS push/pull (--codec): rows DOWN — workers compute on
     # the wire-dequantized tables (STE keeps the embedding gradient
@@ -221,10 +216,12 @@ def run_dlrm(args):
             if codec is not None else None)
 
     @partial(jax.jit, donate_argnums=(0, 1, 2))
+    @jax.named_scope("dlrm.train_step")
     def train_jit_q(params, opt_state, qres, sparse, dense, labels):
         if not use_esd and part is not None:
             sparse = part.to_linear(sparse)
 
+        @jax.named_scope("dlrm.forward")
         def loss_q(p):
             qp = dict(p)
             for kk in quant_keys:
@@ -236,7 +233,8 @@ def run_dlrm(args):
         for kk in quant_keys:
             grads[kk], new_qres[kk] = quantize_with_feedback(
                 grads[kk], qres[kk], codec)
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optim.update"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
         return params, opt_state, new_qres, loss
 
     esd = None
@@ -398,6 +396,7 @@ def run_dlrm(args):
         dec_step = count(start)
 
         @jax.jit
+        @jax.named_scope("esd.decide")
         def with_staged(state, memb):
             # price the staging plane into Alg. 1: a staged row pulls for
             # free, so the dispatch objective sees it as a cluster-resident
@@ -567,11 +566,13 @@ def run_lm(args):
     for i in range(start, args.steps):
         tok = next(stream)
         t0 = time.perf_counter()
-        with get_tracer().span("train.sync", track="train/0", step=i):
+        tr = get_tracer()
+        with tr.span("train.issue", track="train/0", step=i):
             params, opt_state, loss = step(
                 params, opt_state,
                 jax.device_put(jnp.asarray(tok[:, :-1]), tok_shd),
                 jax.device_put(jnp.asarray(tok[:, 1:]), tok_shd))
+        with tr.span("loss.wait", track="train/0", step=i):
             loss = float(loss)
         rec = reg.record_step(i, {"loss": loss,
                                   "wall_s": round(time.perf_counter() - t0,
@@ -683,19 +684,13 @@ def build_parser():
     ap.add_argument("--trace-buffer", type=int, default=65536,
                     help="tracer ring-buffer capacity in spans "
                          "(drop-oldest)")
-    ap.add_argument("--validate-timing", action="store_true",
-                    help="after the run, join traced per-stage wall "
-                         "times against the per-step model predictions "
-                         "(Alg.-1 est/realized cost, transmission cost) "
-                         "and print the prediction-error / ordering-"
-                         "agreement report to stderr")
     return ap
 
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
     use_compile_cache()
-    trace = args.trace_out is not None or args.validate_timing
+    trace = args.trace_out is not None
     tracer = Tracer(capacity=args.trace_buffer) if trace else None
     prev = set_tracer(tracer) if trace else None
     try:
@@ -706,8 +701,7 @@ def main(argv=None):
     finally:
         if trace:
             set_tracer(prev)
-            if args.trace_out is not None:
-                tracer.export(args.trace_out)
+            tracer.export(args.trace_out)
     if trace:
         if tracer.dropped:
             print(f"trace ring dropped {tracer.dropped} oldest spans "
@@ -719,9 +713,6 @@ def main(argv=None):
                       f"total={row['total_s']:.4f}s "
                       f"mean={row['mean_s'] * 1e3:.3f}ms "
                       f"max={row['max_s'] * 1e3:.3f}ms", file=sys.stderr)
-        if args.validate_timing:
-            report = validate_timing(tracer.events(), metrics)
-            print(format_report(report), file=sys.stderr)
     return metrics
 
 

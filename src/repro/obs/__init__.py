@@ -1,10 +1,9 @@
 """repro.obs — observability layer for the ESD stack.
 
 Span tracing (:mod:`.trace`), a unified metrics registry
-(:mod:`.metrics`), predicted-vs-measured timing validation
-(:mod:`.validate`), the shared benchmark artifact schema
-(:mod:`.schema`) and writer (:mod:`.artifacts`), plus the one
-``log_step`` formatter every driver print goes through.
+(:mod:`.metrics`), the shared benchmark artifact schema (:mod:`.schema`)
+and writer (:mod:`.artifacts`), plus the one ``log_step`` formatter
+every driver print goes through.
 """
 from __future__ import annotations
 
@@ -16,7 +15,6 @@ from .trace import (Tracer, NOOP, get_tracer, set_tracer, use_tracer,
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, set_registry, use_registry,
                       STEP_NAMESPACE)
-from .validate import validate_timing, format_report
 from .schema import (Gate, SCHEMAS, SchemaError, bench_name_from_path,
                      validate_bench)
 from .artifacts import write_bench, default_results_dir
@@ -25,7 +23,6 @@ __all__ = [
     "Tracer", "NOOP", "get_tracer", "set_tracer", "use_tracer", "traced",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "get_registry", "set_registry", "use_registry", "STEP_NAMESPACE",
-    "validate_timing", "format_report",
     "Gate", "SCHEMAS", "SchemaError", "bench_name_from_path",
     "validate_bench", "write_bench", "default_results_dir",
     "log_step",

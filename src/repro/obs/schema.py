@@ -158,13 +158,6 @@ SCHEMAS: dict[str, list[Gate]] = {
         # real-clock driver smoke (full runs only): wall clock, positive
         Gate("driver.p99_ms", "gt", 0.0, required=False),
     ],
-    "obs": [
-        Gate("bitwise.identical", "is_true"),
-        Gate("overhead.frac", "le", 0.03),
-        Gate("overlap.increases_with_depth", "is_true"),
-        Gate("trace.valid", "is_true"),
-        Gate("trace.n_events", "gt", 0),
-    ],
 }
 
 _NAME_RE = re.compile(r"^BENCH_([a-z0-9_]+?)(_quick)?\.json$")

@@ -36,6 +36,15 @@ Timing semantics: a span measures host wall time between enter and exit.
 On the jitted path that is *issue* time for asynchronously dispatched
 stages and issue+sync time for stages that block on a concrete value —
 the pipelined runner documents which of its spans mean what.
+
+One clock with the device: while a :class:`Tracer` is installed, every
+``span`` also enters a ``jax.profiler.TraceAnnotation`` of the same name
+(span args as its metadata, e.g. ``step``).  Inside any ``jax.profiler``
+capture the program's spans then sit on the ``/host:`` plane, on the
+profiler's clock, next to the device's operations.  ``start_span``
+handles are left out: they may end out of order on their thread (the
+runner's overlapping ``train/<slot>`` windows), which a TraceMe stack
+cannot represent.
 """
 from __future__ import annotations
 
@@ -46,6 +55,8 @@ import threading
 import time
 from typing import Any, Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "NOOP", "get_tracer", "set_tracer", "use_tracer",
            "traced"]
 
@@ -53,16 +64,21 @@ __all__ = ["Tracer", "NOOP", "get_tracer", "set_tracer", "use_tracer",
 class Span:
     """Open span handle; context manager or explicit ``.end()``."""
 
-    __slots__ = ("_tracer", "name", "track", "args", "thread", "t0", "_open")
+    __slots__ = ("_tracer", "name", "track", "args", "thread", "t0", "_open",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: Optional[str],
-                 args: dict):
+                 args: dict, annotate: bool = True):
         self._tracer = tracer
         self.name = name
         self.track = track
         self.args = args
         self.thread = threading.current_thread().name
         self._open = True
+        self._annotation = None
+        if annotate:
+            self._annotation = TraceAnnotation(name, **args)
+            self._annotation.__enter__()
         self.t0 = tracer.clock()
 
     def end(self) -> None:
@@ -70,6 +86,8 @@ class Span:
             return
         self._open = False
         t1 = self._tracer.clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         self._tracer._record(self.name, self.track, self.thread,
                              self.t0, t1, self.args)
 
@@ -139,11 +157,16 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, track: Optional[str] = None, **args) -> Span:
-        """Open a span; close it with ``.end()`` or a ``with`` block."""
+        """Open a span; close it with ``.end()`` or a ``with`` block.  It
+        is also a profiler annotation, so it must close before any span
+        opened after it on the same thread."""
         return Span(self, name, track, args)
 
-    # same call, different intent: a handle that outlives the call site
-    start_span = span
+    def start_span(self, name: str, track: Optional[str] = None,
+                   **args) -> Span:
+        """A handle that outlives the call site and may end in any order;
+        recorded here only, not as a profiler annotation."""
+        return Span(self, name, track, args, annotate=False)
 
     def _record(self, name, track, thread, t0, t1, args) -> None:
         with self._lock:

@@ -69,28 +69,41 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Callable, Iterable, Optional
 
+import jax
+
 from repro.obs.trace import get_tracer
 
 from .double_buffer import db_commit, db_init
 
 __all__ = ["PipelinedRunner"]
 
-# Tracing semantics (all spans are host wall time; a no-op unless a
-# tracer is installed via repro.obs, so the traced and untraced loops
-# are bitwise identical):
-#   * "decide" / "repair" / "realized" / "advance" live on the "decide"
-#     track and measure issue time of their jitted stage (jax dispatches
-#     asynchronously; these return before the device finishes).
-#   * "train" is the *in-flight window* of a step: opened when the
-#     step's chain is fully issued (it enters `pending`) and closed when
-#     its drain completes.  Windows of consecutive steps overlap at
-#     depth >= 2, so each lives on its own per-slot track
-#     ("train/<t mod depth>") — decide spans for later steps fall inside
-#     them, which is exactly the decision hiding the exported trace
-#     should show.
-#   * "train.sync" (nested inside the window, same track) is the
-#     blocking part of the drain: train_fn issue plus the record_fn
-#     sync on the concrete loss.
+# Tracing semantics (host wall time; a no-op unless a tracer is installed
+# via repro.obs, so the traced and untraced loops are bitwise identical).
+# The main thread's spans tile the loop and never nest, so whatever the
+# host does between two device programs falls in exactly one of them:
+#   * "batch.next": pulling the next batch (loader wait, lookahead
+#     window, device_put) — track "batch".
+#   * "decide" / "repair" / "realized" / "advance": issue time of their
+#     jitted stage (jax dispatches asynchronously; these return before
+#     the device finishes) — track "decide".
+#   * "train.issue" (the train_fn call), "loss.wait" (the block on the
+#     step's loss) and "record" (record_fn) — the drain of a step, on
+#     that step's "train/<t mod depth>" track.
+# "train" is the *in-flight window* of a step: opened when the step's
+# chain is fully issued (it enters `pending`) and closed when its drain
+# completes.  Windows of consecutive steps overlap at depth >= 2, so each
+# lives on its own per-slot track — decide spans for later steps fall
+# inside them, which is exactly the decision hiding the exported trace
+# should show.  Spans a stage opens itself (e.g. "prefetch.pull") nest
+# inside that stage's span.
+
+_END = object()
+
+
+def _next_batch(tr, it, step: int):
+    """``next(it)`` under the "batch.next" span; ``_END`` when exhausted."""
+    with tr.span("batch.next", track="batch", step=step):
+        return next(it, _END)
 
 
 class PipelinedRunner:
@@ -149,9 +162,8 @@ class PipelinedRunner:
         state = self.esd_state
         t = 0
         while steps is None or t < steps:
-            try:
-                batch = next(it)
-            except StopIteration:
+            batch = _next_batch(tr, it, t)
+            if batch is _END:
                 break
             committed = db.front if self.stale else state
             decide_state = db.back if self.stale else state
@@ -205,9 +217,8 @@ class PipelinedRunner:
         while steps is None or t < steps:
             while (len(decided) <= ahead and not exhausted
                    and (steps is None or pulled < steps)):
-                try:
-                    batch = next(it)
-                except StopIteration:
+                batch = _next_batch(tr, it, pulled)
+                if batch is _END:
                     exhausted = True
                     break
                 with tr.span("decide", track="decide", step=pulled):
@@ -250,9 +261,13 @@ class PipelinedRunner:
 
     def _drain_one(self, pending: deque, record_fn: Optional[Callable]):
         t, train_input, aux, info, window = pending.popleft()
+        tr = get_tracer()
         try:
-            with get_tracer().span("train.sync", track=window.track, step=t):
+            with tr.span("train.issue", track=window.track, step=t):
                 loss = self.train_fn(train_input)
+            with tr.span("loss.wait", track=window.track, step=t):
+                jax.block_until_ready(loss)
+            with tr.span("record", track=window.track, step=t):
                 if record_fn is None:
                     return {"step": t, "loss": float(loss)}
                 return record_fn(t, loss, aux, info)

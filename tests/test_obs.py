@@ -1,5 +1,5 @@
 """Tests for repro.obs: span tracing, the metrics registry, the bench
-artifact schema/writer, log_step, and predicted-vs-measured validation."""
+artifact schema/writer and log_step."""
 import io
 import json
 import math
@@ -14,7 +14,6 @@ from repro.obs import (
     SchemaError,
     Tracer,
     bench_name_from_path,
-    format_report,
     get_registry,
     get_tracer,
     log_step,
@@ -23,7 +22,6 @@ from repro.obs import (
     use_tracer,
     traced,
     validate_bench,
-    validate_timing,
     write_bench,
 )
 from repro.obs.schema import _check_gate, _sweep_finite
@@ -217,9 +215,110 @@ class TestRunnerBitwise:
         tr = Tracer(capacity=256)
         self._records(2, tracer=tr)
         names = {e["name"] for e in tr.events()}
-        assert {"decide", "advance", "train", "train.sync"} <= names
+        assert {"batch.next", "decide", "advance", "train", "train.issue",
+                "loss.wait", "record"} <= names
         tracks = {e["track"] for e in tr.events() if e["name"] == "train"}
         assert tracks == {"train/0", "train/1"}
+
+
+class TestRunnerPhases:
+    """The runner's main-thread spans tile its loop: they never overlap,
+    and every batch pull, stage call and record falls inside the span of
+    its phase, so a host gap between two device programs lies in exactly
+    one of them."""
+
+    PHASES = ("batch.next", "decide", "advance", "train.issue",
+              "loss.wait", "record")
+
+    @staticmethod
+    def _run(depth, ahead, tracer, steps=6):
+        calls = []                      # (phase, host time inside it)
+
+        def mark(phase):
+            calls.append((phase, time.perf_counter()))
+
+        def batches():
+            for b in range(steps):
+                mark("batch.next")
+                yield b
+
+        def decide(state, batch):
+            mark("decide")
+            return batch % 3, None
+
+        def advance(state, batch, assign):
+            mark("advance")
+            return (batch, assign), state + 1, {}
+
+        def train(x):
+            mark("train.issue")
+            return math.sin(x[0] * 1.7 + x[1])
+
+        def record(t, loss, aux, info):
+            mark("record")
+            return {"step": t, "loss": loss}
+
+        def repair(committed, decided, batch, assign):
+            mark("repair")
+            return assign, {}
+
+        r = PipelinedRunner(decide, advance, train, 0, depth=depth,
+                            decide_ahead=ahead,
+                            repair_fn=repair if ahead else None)
+        with use_tracer(tracer):
+            recs = r.run(batches(), record_fn=record)
+        return recs, calls
+
+    @pytest.mark.parametrize("depth,ahead", [(1, 0), (2, 0), (2, 1)],
+                             ids=["depth1", "depth2", "decide_ahead1"])
+    def test_main_thread_spans_tile_the_loop(self, depth, ahead):
+        tr = Tracer(capacity=1024)
+        recs, calls = self._run(depth, ahead, tr)
+        assert [r["step"] for r in recs] == list(range(6))
+        flat = sorted((e for e in tr.events() if e["name"] != "train"),
+                      key=lambda e: e["ts"])
+        assert {e["thread"] for e in flat} == {"MainThread"}
+        for a, b in zip(flat, flat[1:]):            # no nesting, no overlap
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-9, (a, b)
+        for phase, at in calls:
+            at -= tr.t0
+            assert any(e["name"] == phase
+                       and e["ts"] - 1e-9 <= at <= e["ts"] + e["dur"] + 1e-9
+                       for e in flat), phase
+        counts = {n: sum(e["name"] == n for e in flat) for n in self.PHASES}
+        # six batches and the pull that finds the stream ended
+        assert counts == dict.fromkeys(self.PHASES, 6) | {"batch.next": 7}
+        assert sum(e["name"] == "repair" for e in flat) == (6 if ahead else 0)
+        windows = [e for e in tr.events() if e["name"] == "train"]
+        assert len(windows) == 6
+
+    @staticmethod
+    def _host_names(tmp_path, tracer):
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            TestRunnerPhases._run(2, 0, tracer)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        data = jax.profiler.ProfileData.from_file(str(path))
+        host = [p for p in data.planes if p.name.startswith("/host:")]
+        return [(ev.name, {k: v for k, v in ev.stats}) for p in host
+                for ln in p.lines for ev in ln.events]
+
+    def test_profiler_capture_holds_the_phases(self, tmp_path):
+        events = self._host_names(tmp_path, Tracer(capacity=1024))
+        names = {n for n, _ in events}
+        assert set(self.PHASES) <= names
+        steps = sorted(st["step"] for n, st in events if n == "record")
+        assert steps == list(range(6))
+        # the in-flight windows end out of order: not annotations
+        assert "train" not in names
+
+    def test_no_tracer_no_annotation(self, tmp_path):
+        names = {n for n, _ in self._host_names(tmp_path, None)}
+        assert not set(self.PHASES) & names
 
 
 # ------------------------------------------------------------- registry
@@ -404,111 +503,50 @@ class TestSchema:
         assert errors and "not a finite number" in errors[0]
 
     def test_bench_name_from_path(self):
-        assert bench_name_from_path("BENCH_obs.json") == "obs"
-        assert bench_name_from_path("/a/b/BENCH_obs_quick.json") == "obs"
+        assert bench_name_from_path("BENCH_quant.json") == "quant"
+        assert bench_name_from_path("/a/b/BENCH_quant_quick.json") == "quant"
         assert bench_name_from_path("BENCH_multips_quick.json") == "multips"
         assert bench_name_from_path("notes.json") is None
 
     def test_validate_bench_reports_all_violations(self):
         with pytest.raises(SchemaError) as e:
-            validate_bench("obs", {"bitwise": {"identical": False},
-                                   "overhead": {"frac": 0.5},
-                                   "overlap": {"increases_with_depth": True},
-                                   "trace": {"valid": True, "n_events": 3}})
+            validate_bench("quant", {
+                "results": {"fp32": {"final_loss": 50.0},
+                            "int8": {"quant": {"byte_reduction": 1.0}}}})
         msg = str(e.value)
-        assert "bitwise.identical" in msg and "overhead.frac" in msg
+        assert ("results.fp32.final_loss" in msg
+                and "results.int8.quant.byte_reduction" in msg)
 
 
 class TestWriteBench:
-    GOOD = {"bitwise": {"identical": True}, "overhead": {"frac": 0.001},
-            "overlap": {"increases_with_depth": True},
-            "trace": {"valid": True, "n_events": 10}}
+    GOOD = {"results": {"fp32": {"final_loss": 0.5},
+                        "int8": {"quant": {"byte_reduction": 4.0}}}}
 
     def test_writes_canonical_and_quick_paths(self, tmp_path):
-        p = write_bench("obs", self.GOOD, results_dir=tmp_path)
-        assert p == tmp_path / "BENCH_obs.json"
-        q = write_bench("obs", self.GOOD, quick=True, results_dir=tmp_path)
-        assert q == tmp_path / "BENCH_obs_quick.json"
+        p = write_bench("quant", self.GOOD, results_dir=tmp_path)
+        assert p == tmp_path / "BENCH_quant.json"
+        q = write_bench("quant", self.GOOD, quick=True, results_dir=tmp_path)
+        assert q == tmp_path / "BENCH_quant_quick.json"
         assert json.loads(p.read_text()) == self.GOOD
         assert not list(tmp_path.glob("*.tmp"))   # atomic: no leftovers
 
     def test_out_override(self, tmp_path):
-        p = write_bench("obs", self.GOOD, out=tmp_path / "x.json")
+        p = write_bench("quant", self.GOOD, out=tmp_path / "x.json")
         assert p == tmp_path / "x.json" and p.exists()
 
     def test_invalid_report_never_touches_disk(self, tmp_path):
-        bad = {"bitwise": {"identical": False}, "overhead": {"frac": 0.9},
-               "overlap": {"increases_with_depth": False},
-               "trace": {"valid": False, "n_events": 0}}
+        bad = {"results": {"fp32": {"final_loss": 50.0},
+                           "int8": {"quant": {"byte_reduction": 1.0}}}}
         with pytest.raises(SchemaError):
-            write_bench("obs", bad, results_dir=tmp_path)
+            write_bench("quant", bad, results_dir=tmp_path)
         assert list(tmp_path.iterdir()) == []
 
     def test_mirrors_gauges_into_registry(self, tmp_path):
         with use_registry() as reg:
-            write_bench("obs", self.GOOD, results_dir=tmp_path)
-        assert reg.value("bench.obs.overhead.frac") == 0.001
-        assert reg.value("bench.obs.trace.n_events") == 10
-
-
-# ----------------------------------------------------- validate_timing
-
-def _ev(name, track, ts, dur, **args):
-    return {"name": name, "track": track, "thread": "t",
-            "ts": ts, "dur": dur, "args": args}
-
-
-class TestValidateTiming:
-    def test_overlap_union_of_train_windows(self):
-        events = [
-            _ev("train", "train/0", 0.0, 2.0, step=0),
-            _ev("train", "train/1", 1.5, 1.0, step=1),   # overlaps slot 0
-            _ev("decide", "decide", 1.0, 1.0, step=1),   # fully hidden
-            _ev("decide", "decide", 3.0, 1.0, step=2),   # not hidden
-            _ev("advance", "decide", 0.0, 5.0, step=0),  # ignored: not decide
-        ]
-        ov = validate_timing(events, [])["overlap"]
-        assert ov["decide_total_s"] == 2.0
-        assert ov["decide_hidden_s"] == 1.0    # union, not double-counted
-        assert ov["hidden_frac"] == 0.5
-        assert ov["n_train_windows"] == 2
-
-    def test_depth1_has_zero_overlap(self):
-        events = [_ev("train", "train/0", 1.0, 1.0, step=0),
-                  _ev("decide", "decide", 0.0, 1.0, step=0),
-                  _ev("decide", "decide", 2.0, 1.0, step=1)]
-        assert validate_timing(events, [])["overlap"]["hidden_frac"] == 0.0
-
-    def test_alg1_ordering_agreement(self):
-        steps = [{"step": 0, "alg1_est": 1.0, "alg1_realized": 1.0},
-                 {"step": 1, "alg1_est": 2.0, "alg1_realized": 3.0},
-                 {"step": 2, "alg1_est": 3.0, "alg1_realized": 2.0}]
-        a = validate_timing([], steps)["alg1"]
-        assert a["n"] == 3
-        o = a["ordering"]
-        assert (o["concordant"], o["discordant"]) == (2, 1)
-        assert o["agreement"] == pytest.approx(2 / 3)
-        assert o["flagged"] == [{"a": 1, "b": 2}]
-        assert a["rel_error"]["max"] == pytest.approx(0.5)
-
-    def test_predicted_vs_wall_joins_on_step(self):
-        events = [_ev("decide", "decide", 0.0, 0.1, step=0),
-                  _ev("decide", "decide", 1.0, 0.3, step=1),
-                  _ev("decide", "decide", 2.0, 0.2, step=2)]
-        steps = [{"step": 0, "cost": 1.0}, {"step": 1, "cost": 3.0},
-                 {"step": 2, "cost": 2.0}]
-        p = validate_timing(events, steps)["predicted_vs_wall"]
-        assert p["train.sync"] is None          # no such spans
-        d = p["decide"]
-        assert d["n"] == 3
-        assert d["ordering"]["agreement"] == 1.0   # perfect rank match
-
-    def test_format_report_renders(self):
-        events = [_ev("decide", "decide", 0.0, 0.1, step=0),
-                  _ev("train", "train/0", 0.0, 1.0, step=0)]
-        steps = [{"step": 0, "loss": 1.0}]
-        text = format_report(validate_timing(events, steps))
-        assert "timing validation" in text and "decide" in text
+            write_bench("quant", self.GOOD, results_dir=tmp_path)
+        assert reg.value("bench.quant.results.fp32.final_loss") == 0.5
+        assert reg.value(
+            "bench.quant.results.int8.quant.byte_reduction") == 4.0
 
 
 # ------------------------------------------------- driver integration
