@@ -506,25 +506,21 @@ def esd_state_update_sparse(state: SparseEsdState, need_ids: jnp.ndarray,
                     f"{offs[-1]}; "
                     "init the state with esd_sparse_init(..., capacity_ps, "
                     "max_ids=L)")
-            imax = jnp.iinfo(jnp.int32).max
             shard_need = part.shard_of_linear(jnp.where(valid, need_ids, 0))
             new_segs, ev_counts = [], []
             for p, cap_p in enumerate(capacity_ps):
                 valid_p = valid & (shard_need == p)
                 need_p = jnp.where(valid_p, need_ids, -1)
                 slots_p = state.slots[:, offs[p]:offs[p] + cap_p + L]
-                need_sorted = jnp.sort(jnp.where(valid_p, need_ids, imax),
-                                       axis=1)
-                hit = jnp.take_along_axis(
-                    need_sorted,
-                    jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots_p),
-                             0, L - 1),
-                    axis=1)
-                slot_cand = jnp.where((hit == slots_p) & (slots_p >= 0), -1,
+                # segment p holds only ids homed at shard p, so the stamp
+                # test (see the single-budget branch) is membership of need_p
+                la_s = last_access[rows, jnp.clip(slots_p, 0, V - 1)]
+                slot_cand = jnp.where((la_s == step) & (slots_p >= 0), -1,
                                       slots_p)
                 cand = jnp.concatenate([need_p, slot_cand], axis=1)
-                gc = jnp.clip(cand, 0, V - 1)
-                la_c = jnp.where(cand >= 0, last_access[rows, gc], -1)
+                la_c = jnp.concatenate(
+                    [jnp.where(valid_p, step, -1),
+                     jnp.where(slot_cand >= 0, la_s, -1)], axis=1)
                 sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
                 T_p = cand.shape[1]                      # = cap_p + 2L
                 zone = slice(T_p - cap_p - 2 * L, T_p - cap_p)
@@ -559,25 +555,21 @@ def esd_state_update_sparse(state: SparseEsdState, need_ids: jnp.ndarray,
                     "esd_sparse_init(..., capacity, max_ids=L)")
             S = slots.shape[1]
             # candidates: this step's ids (pinned) + previous survivors with
-            # duplicates of this step's ids masked out
-            imax = jnp.iinfo(jnp.int32).max
-            need_sorted = jnp.sort(jnp.where(valid, need_ids, imax), axis=1)
-            hit = jnp.take_along_axis(
-                need_sorted,
-                jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots), 0,
-                         L - 1),
-                axis=1)
-            slot_cand = jnp.where((hit == slots) & (slots >= 0), -1, slots)
+            # duplicates of this step's ids masked out.  Slot s is in row j's
+            # need list iff Phase C stamped last_access[j, s] = step: every
+            # other stamp is at most step - 1, so no search is needed.
+            la_s = last_access[rows, jnp.clip(slots, 0, V - 1)]      # (n, S)
+            slot_cand = jnp.where((la_s == step) & (slots >= 0), -1, slots)
             cand = jnp.concatenate(
                 [jnp.where(valid, need_ids, -1), slot_cand], axis=1)   # (n, T)
-            cvalid = cand >= 0
-            gc = jnp.clip(cand, 0, V - 1)
             # two-key lexicographic sort on (last_access, id): same strict
             # order as the dense engine's cut without the int32 overflow a
             # packed la*V + id key would hit at paper scale (x64 disabled).
             # Invalid candidates get la = -1 so they sort below every valid
-            # one (valid la >= 0).
-            la_c = jnp.where(cvalid, last_access[rows, gc], -1)
+            # one (valid la >= 0); this step's ids were just stamped step.
+            la_c = jnp.concatenate(
+                [jnp.where(valid, step, -1),
+                 jnp.where(slot_cand >= 0, la_s, -1)], axis=1)
             sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
             T = cand.shape[1]
 

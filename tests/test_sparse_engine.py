@@ -6,7 +6,8 @@ The sparse engine's contract is *exact* equivalence:
   * cache: SparseClusterCache reproduces ClusterCache's counts AND planes
     over multi-iteration traces (all policies, both sync modes);
   * in-jit state: esd_state_update_sparse reproduces esd_state_update's
-    counts/planes including the bounded-candidate LRU cut;
+    counts/planes including the bounded-candidate LRU cut, and its cut's
+    stamp hit test reproduces the binary search it replaced;
   * simulator: engine="sparse" and engine="dense" produce identical
     SimResults (identical assignments -> identical transmission costs).
 """
@@ -33,7 +34,9 @@ from repro.core.dispatch_tpu import (
     esd_state_update,
     esd_state_update_sparse,
 )
+from repro.elastic import mask_state
 from repro.kernels import cost_matrix_pallas, cost_matrix_pallas_sparse
+from repro.ps import make_partition
 
 
 def _instance(rng, n=4, V=200, k=16, F=6, pad_frac=0.15, dup=True):
@@ -302,6 +305,148 @@ class TestSparseEdgeCases:
                                          jnp.asarray(dirty), jnp.asarray(t))
         np.testing.assert_allclose(np.asarray(got_jnp), want, rtol=1e-6)
         assert (want == want[0]).all()      # identical rows
+
+
+def _search_hit(need, slots):
+    """The cut's former hit test: each slot id looked up by binary search
+    in its row's sorted need list (PAD -1)."""
+    L = need.shape[1]
+    need_sorted = jnp.sort(
+        jnp.where(need >= 0, need, jnp.iinfo(jnp.int32).max), axis=1)
+    pos = jnp.clip(jax.vmap(jnp.searchsorted)(need_sorted, slots), 0, L - 1)
+    return ((jnp.take_along_axis(need_sorted, pos, axis=1) == slots)
+            & (slots >= 0))
+
+
+def _search_cut(latest, dirty, last_access, slots, need, step, cap):
+    """One budget's LRU cut over ``need`` (n, L) and its slot segment,
+    built on :func:`_search_hit`."""
+    n, L = need.shape
+    V = latest.shape[1]
+    rows = jnp.arange(n)[:, None]
+    cand = jnp.concatenate(
+        [need, jnp.where(_search_hit(need, slots), -1, slots)], axis=1)
+    la_c = jnp.where(cand >= 0, last_access[rows, jnp.clip(cand, 0, V - 1)],
+                     -1)
+    sla, sid = jax.lax.sort((la_c, cand), dimension=1, num_keys=2)
+    T, S = cand.shape[1], slots.shape[1]
+    zone = slice(T - cap - 2 * L, T - cap)
+    ev = (sla[:, zone] >= 0) & (sla[:, zone] < step)
+    ev_ids = jnp.where(ev, sid[:, zone], V)
+    egc = jnp.minimum(ev_ids, V - 1)
+    pushed = (latest[rows, egc] & dirty[rows, egc] & ev).sum(axis=1)
+    latest = latest.at[rows, ev_ids].set(False, mode="drop")
+    dirty = dirty.at[rows, ev_ids].set(False, mode="drop")
+    top_la, top_id = sla[:, T - S:], sid[:, T - S:]
+    keep = (top_la >= 0) & ((jnp.arange(S) >= S - cap)[None, :]
+                            | (top_la == step))
+    return (latest, dirty, jnp.where(keep, top_id, -1),
+            pushed.astype(jnp.int32))
+
+
+def _search_step(state, need_ids, capacity, part):
+    """esd_state_update_sparse with the cut rebuilt on the search: its
+    phases (capacity=None leaves the slots alone), then the old cut."""
+    mid, counts = esd_state_update_sparse(state, need_ids, None, part)
+    L = need_ids.shape[1]
+    valid = need_ids >= 0
+    latest, dirty = mid.latest, mid.dirty
+    if isinstance(capacity, tuple):
+        shard = part.shard_of_linear(jnp.where(valid, need_ids, 0))
+        segs, pushed, off = [], [], 0
+        for p, cap in enumerate(capacity):
+            latest, dirty, seg, ev = _search_cut(
+                latest, dirty, mid.last_access,
+                state.slots[:, off:off + cap + L],
+                jnp.where(valid & (shard == p), need_ids, -1), mid.step, cap)
+            segs.append(seg)
+            pushed.append(ev)
+            off += cap + L
+        slots = jnp.concatenate(segs, axis=1)
+        counts["evict_push"] = sum(pushed)
+        counts["evict_push_ps"] = jnp.stack(pushed, axis=1)
+    else:
+        latest, dirty, slots, counts["evict_push"] = _search_cut(
+            latest, dirty, mid.last_access, state.slots,
+            jnp.where(valid, need_ids, -1), mid.step, capacity)
+    return dataclasses.replace(mid, latest=latest, dirty=dirty,
+                               slots=slots), counts
+
+
+class TestStampHitTest:
+    """The LRU cut tells a surviving slot that is needed again this step
+    by its Phase-C stamp (last_access == step), not by searching the need
+    list; state and counts stay bitwise those of the search."""
+    _step = staticmethod(
+        jax.jit(esd_state_update_sparse, static_argnums=(2, 3)))
+    _search = staticmethod(jax.jit(_search_step, static_argnums=(2, 3)))
+
+    @pytest.mark.parametrize("case", ["spill", "all_pad_rows",
+                                      "row_in_slots", "worker_dead",
+                                      "per_ps"])
+    def test_state_and_counts_match_search(self, case):
+        n, V, L, capacity, part = 3, 120, 12, 20, None
+        if case == "spill":
+            capacity = 5                   # batches of up to 12 ids pin over it
+        if case == "per_ps":
+            part = make_partition(V, 2)
+            capacity = (9, 5)
+        ids_space = (np.arange(V) if part is None
+                     else np.asarray(part.to_linear(np.arange(V))))
+        Vs = V if part is None else part.linear_size
+        state = ref = esd_sparse_init(n, Vs, capacity, L)
+        r = np.random.default_rng(17)
+        evicted = 0
+        for it in range(16):
+            dead = case == "worker_dead" and 5 <= it < 9
+            if dead:                       # worker 1 away, then a cold rejoin
+                active = np.arange(n) != 1
+                state, ref = mask_state(state, active), mask_state(ref, active)
+            ids = np.full((n, L), -1, np.int32)
+            for j in range(n):
+                pad_row = case == "all_pad_rows" and (it % 4 == 1
+                                                      or r.random() < 0.3)
+                if pad_row or (dead and j == 1):
+                    continue
+                pool = ids_space
+                if case == "row_in_slots" and j == 0 and it % 2:
+                    pool = np.asarray(state.slots[0])
+                    pool = pool[pool >= 0]
+                    k = min(L, len(pool))
+                else:
+                    k = int(r.integers(0, L + 1))
+                ids[j, r.choice(L, k, replace=False)] = r.choice(
+                    pool, k, replace=False)
+            state, c = self._step(state, jnp.asarray(ids), capacity, part)
+            ref, c_ref = self._search(ref, jnp.asarray(ids), capacity, part)
+            evicted += int(np.asarray(c_ref["evict_push"]).sum())
+            assert c.keys() == c_ref.keys()
+            for key in c:
+                np.testing.assert_array_equal(
+                    np.asarray(c[key]), np.asarray(c_ref[key]),
+                    err_msg=f"{case} it{it} {key}")
+            for f in ("slots", "latest", "dirty", "last_access", "step"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(state, f)),
+                    np.asarray(getattr(ref, f)), err_msg=f"{case} it{it} {f}")
+        assert evicted > 0                 # the trace reaches the cut
+
+    @pytest.mark.parametrize("budget", ["single", "per_ps"])
+    def test_cut_adds_no_loop(self, budget):
+        """The only loop left is the universe's searchsorted: lowering with
+        a capacity gives as many stablehlo.while ops as without one."""
+        n, V, L, capacity, part = 2, 64, 8, 10, None
+        if budget == "per_ps":
+            part = make_partition(V, 2)
+            V, capacity = part.linear_size, (6, 4)
+        step = jax.jit(esd_state_update_sparse, static_argnums=(2, 3))
+
+        def n_while(cap):
+            state = esd_sparse_init(n, V, cap, L)
+            return step.lower(state, jnp.zeros((n, L), jnp.int32), cap,
+                              part).as_text().count("stablehlo.while")
+
+        assert n_while(capacity) == n_while(None)
 
 
 class TestSimulatorEquivalence:
