@@ -38,10 +38,10 @@ def controls(cell, seed: int) -> list[dict]:
 
     from bench import checks, reference, traffic
 
-    model = reference.Model(cell.config)
+    model = reference.Model(cell.config, cell.root)
     t = cell.traffic
     k = int(t["batch_per_worker"]) * cell.chips
-    sampler = traffic.CTRSampler(cell.config["tables"])
+    sampler = traffic.sampler(cell.config, cell.root)
     batches = [b for _, b in zip(range(3), sampler.batches(seed + 1, k))]
     lr = float(t["lr"])
     ref = reference.train_steps(model, seed, batches, lr)
@@ -70,7 +70,8 @@ def main(argv=None) -> int:
 
     for seed in args.seeds:
         out = train_cell.run(cell.entry["config"], cell.config, cell.traffic,
-                             seed, args.seconds, False, device)
+                             seed, args.seconds, False, device,
+                             root=cell.root)
         correct, _ = checks.judge(out["checks"], cell.limits)
         print(json.dumps(run._plain({
             "kind": "program", "seed": seed, "correct": correct,

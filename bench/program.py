@@ -42,22 +42,34 @@ class BenchWorkload(CTRWorkload):
         return self.feed.stream(self.sampler, seed, batch)
 
 
+def _fields(cls, values) -> dict:
+    """The entries of ``values`` that name a field of dataclass ``cls``,
+    with lists as tuples."""
+    names = {f.name for f in dataclasses.fields(cls)}
+    return {k: tuple(v) if isinstance(v, list) else v
+            for k, v in values.items() if k in names}
+
+
 @contextlib.contextmanager
 def registered(name: str, cfg: dict, sampler: CTRSampler, feed=None):
     """Register configuration ``cfg``, drawing its samples from
     ``sampler``, under ``bench.<name>`` for the length of the block;
-    yields the name the program's ``--arch`` takes."""
+    yields the name the program's ``--arch`` takes.
+
+    Every key of ``cfg`` that names a field of the program's
+    ``DLRMConfig`` reaches it as it stands (``cross_layers`` is 0 where
+    it states none), and every attribute of ``sampler`` that names
+    a field of ``CTRWorkload`` reaches the workload, so a field a later
+    program adds needs no edit here."""
     key = f"bench.{name}"
-    WORKLOADS[key] = BenchWorkload(
-        name=key, model=cfg["kind"], table_sizes=sampler.sizes,
-        zipf_a=sampler.zipf_a, n_dense=sampler.n_dense,
-        n_groups=sampler.n_groups, group_frac=sampler.group_frac,
-        hist_max=sampler.hist_max, hist_mean=sampler.hist_mean,
-        sampler=sampler, feed=feed)
-    DLRM_CONFIGS[key] = DLRMConfig(
-        key, cfg["kind"], key, embedding_dim=int(cfg["embedding_dim"]),
-        n_dense=sampler.n_dense, mlp_dims=tuple(cfg["mlp_dims"]),
-        cross_layers=int(cfg.get("cross_layers", 0)))
+    given = {f.name: getattr(sampler, f.name)
+             for f in dataclasses.fields(CTRWorkload)
+             if hasattr(sampler, f.name)}
+    WORKLOADS[key] = BenchWorkload(**_fields(CTRWorkload, given) | dict(
+        name=key, model=cfg["kind"], sampler=sampler, feed=feed))
+    DLRM_CONFIGS[key] = DLRMConfig(**_fields(
+        DLRMConfig, {"cross_layers": 0} | cfg) | dict(
+        name=key, workload=key, n_dense=sampler.n_dense))
     try:
         yield key
     finally:

@@ -6,8 +6,10 @@
 The cell is looked up by name in ``BENCHMARK.json`` at the root of the
 checkout; its configuration (``bench/configs/<config>.json``), traffic
 mix (``bench/traffic/<traffic>.json``), limits of the check
-(``bench/limits/<workload>.json``) and, with ``--trace 1``, its
-per-layer metrics (``bench/metrics/<metric>.py``) are found by name.
+(``bench/limits/<workload>.json``), model kind
+(``bench/models/<kind>.py``, the configuration's ``kind``) and, with
+``--trace 1``, its per-layer metrics (``bench/metrics/<metric>.py``)
+are found by name.
 The traffic mix's ``kind`` names the driver (``train``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
@@ -46,6 +48,7 @@ class Cell:
             raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
         self.entry = cells[name]
         self.name = name
+        self.root = root
         self.chips = int(self.entry["chips"])
         d = root / "bench"
         self.config = _load(d / "configs" / f"{self.entry['config']}.json")
@@ -113,7 +116,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         raise SystemExit(f"unknown traffic kind {kind!r}")
     try:
         out = drivers[kind](cell.entry["config"], cell.config, cell.traffic,
-                            seed, seconds, trace, device)
+                            seed, seconds, trace, device, root=cell.root)
     except Exception:
         traceback.print_exc()
         return {"correct": False, "attempted": 0, "failed": 0, "metrics": {},

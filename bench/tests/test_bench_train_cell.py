@@ -4,7 +4,7 @@ underneath (a step that returns its state unchanged, half of each batch
 left out with the mean over the rest) it is not."""
 import pytest
 
-from bench import program, run
+from bench import program, run, train_cell
 from bench.tests import tiny
 
 
@@ -20,6 +20,13 @@ def test_sound_run_is_correct(cell):
     assert set(res["metrics"]) == {"train_samples_per_s", "setup_s"}
     assert res["metrics"]["train_samples_per_s"]["value"] > 0
     assert list(res)[-1] == "checks"
+
+
+def test_a_new_seed_compiles_no_new_set_up_program(cell):
+    run.run_cell(cell, 2 ** 31 + 11, 1.0, False, tiny.CPU)
+    programs = train_cell._state_norms._cache_size()
+    run.run_cell(cell, 2 ** 31 + 12, 1.0, False, tiny.CPU)
+    assert train_cell._state_norms._cache_size() == programs
 
 
 def _unchanged(name, lr):

@@ -10,7 +10,16 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import models
+
 PAD_ID = -1
+
+
+def sampler(cfg: dict, root=None):
+    """The sample generator of ``cfg``'s model kind over its ``tables``
+    block: the ``Sampler`` its module names, else :class:`CTRSampler`."""
+    kind = models.load(cfg["kind"], root)
+    return getattr(kind, "Sampler", CTRSampler)(cfg["tables"])
 
 
 class CTRSampler:
@@ -32,6 +41,10 @@ class CTRSampler:
         self.offsets = np.concatenate(
             [[0], np.cumsum(self.sizes)[:-1]]).astype(np.int64)
         self._cdfs: dict[tuple[float, int], np.ndarray] = {}
+
+    @property
+    def table_sizes(self) -> tuple:
+        return self.sizes
 
     @property
     def n_fields(self) -> int:

@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import checks, program, reference, work, xplane
-from .traffic import CTRSampler
+from . import checks, program, reference, scopes, work, xplane
+from .traffic import sampler as kind_sampler
 
 
 class Feed:
@@ -128,7 +128,7 @@ def _state_norms(model, params, key, rows):
     for path, p in jax.tree_util.tree_flatten_with_path(params)[0]:
         k = jax.tree_util.keystr(path)
         d = p.astype(jnp.float32) - _at(p0, path)
-        if path[0].key in reference.TABLES:
+        if path[0].key in model.table_leaves:
             per_row = jnp.sum(jnp.square(d), axis=-1)
             change[k] = jnp.sqrt(jnp.sum(per_row))
             drift[k] = jnp.sqrt(jnp.sum(per_row.at[rows].set(0.0)))
@@ -144,19 +144,24 @@ def _at(tree, path):
 
 
 def run(name: str, cfg: dict, traffic: dict, seed: int, seconds: float,
-        trace: bool, device) -> dict:
+        trace: bool, device, root=None) -> dict:
     from repro.launch import train as T
     from repro.obs import MetricsRegistry, Tracer, set_tracer
 
     warmup = int(traffic["warmup_steps"])
     feed = Feed(warmup, float(traffic["trace_seconds"]) if trace else seconds,
                 trace)
-    model = reference.Model(cfg)
+    model = reference.Model(cfg, root)
     k = int(traffic["batch_per_worker"]) * device["count"]
     # the stream's first three batches: the steps the reference follows
-    sampler = CTRSampler(cfg["tables"])
+    sampler = kind_sampler(cfg, root)
     batches = [b for _, b in zip(range(3), sampler.batches(seed + 1, k))]
-    snaps = Snapshots(model, seed, reference.touched_rows(batches))
+    # the touched rows, padded with a repeat to the batches' id slots: one
+    # shape for every seed, so set-up compiles its reads once per cell
+    rows = reference.touched_rows(batches)
+    rows = np.pad(rows, (0, sum(b[0].size for b in batches) - rows.size),
+                  mode="edge")
+    snaps = Snapshots(model, seed, rows)
     compiles = checks.CompileCounter()
 
     class Registry(MetricsRegistry):
@@ -226,21 +231,27 @@ def run(name: str, cfg: dict, traffic: dict, seed: int, seconds: float,
 
 
 def _reduce(feed, tracer, t_close, cfg, k, steps, recs, device):
-    """Per-layer context of a traced run."""
+    """Per-layer context of a traced run: the trace by XLA module and
+    operation (``reduced``), the exclusive device seconds of each
+    ``jax.named_scope`` (``scope_s``, mean over chips) and the host
+    seconds of each of the tracer's spans on the main thread
+    (``host_phase_s``), all over the window."""
     try:
         path = next(Path(feed.profiling).glob("plugins/profile/*/*.xplane.pb"))
         planes = xplane.read_planes(path)
+        space = scopes.read_space(path)
     finally:
         shutil.rmtree(feed.profiling, ignore_errors=True)
     offset = xplane.host_offset(planes, feed.mark_t)
+    events = tracer.events()
     # the host's own work on the main thread names a gap before the
     # loader thread's sampling, which runs beside everything
     spans = [(e["name"], tracer.t0 + e["ts"], tracer.t0 + e["ts"] + e["dur"],
               0 if e["thread"] == "MainThread" else 1)
-             for e in tracer.events() if e["name"] != "train"]
-    red = xplane.reduce(planes, spans, offset,
-                        window_ns=(feed.mark_t * 1e9 + offset,
-                                   t_close * 1e9 + offset))
+             for e in events if e["name"] != "train"]
+    window = (feed.mark_t * 1e9 + offset, t_close * 1e9 + offset)
+    red = xplane.reduce(planes, spans, offset, window_ns=window)
+    named = scopes.reduce(space, window, names={e["name"] for e in events})
     pulled = sum(r.get("prefetch_bytes", 0) for r in recs
                  if r["step"] >= feed.warmup_steps)
     row_bytes = work.F32 * int(cfg["embedding_dim"])
@@ -248,4 +259,5 @@ def _reduce(feed, tracer, t_close, cfg, k, steps, recs, device):
     return {"reduced": red, "steps": len(steps), "rows_per_step": k,
             "distinct_per_step": distinct,
             "rows_pulled": pulled / row_bytes, "cfg": cfg,
-            "peaks": work.peaks(device["kind"])}
+            "peaks": work.peaks(device["kind"]),
+            "scope_s": named.scope_s, "host_phase_s": named.host_s}

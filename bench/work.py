@@ -1,5 +1,6 @@
 """Required work of the benchmarked programs, from shapes and ids, and
-the peaks of the chips they run on.
+the peaks of the chips they run on.  Each model kind counts its own
+work outside the tables (``bench/models/<kind>.py``).
 
 "Required" is the least any implementation of the same computation has
 to do: each distinct embedding row read and written once, each dense
@@ -9,6 +10,8 @@ parameter read and written once, and the model's multiply-adds.  A share built o
 from __future__ import annotations
 
 import numpy as np
+
+from . import models
 
 # Published peaks per chip, keyed by jax's device_kind.  Source: Google
 # Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
@@ -33,55 +36,28 @@ def distinct_ids(sparse: np.ndarray) -> int:
     return int(np.unique(ids[ids >= 0]).size)
 
 
-def _mlp_macs(din, dims) -> int:
-    macs = 0
-    for d in dims:
-        macs += din * d
-        din = d
-    return macs
+def dense_params(cfg: dict, root=None) -> int:
+    """Parameters outside the embedding tables, by the configuration's
+    kind (``bench/models/<kind>.py``)."""
+    return models.load(cfg["kind"], root).dense_params(cfg)
 
 
-def dense_params(cfg: dict) -> int:
-    """Parameters outside the embedding tables."""
-    E, mlp = int(cfg["embedding_dim"]), tuple(cfg["mlp_dims"])
-    F = len(cfg["tables"]["sizes"])
-    n_dense = int(cfg["tables"]["n_dense"])
-    inter = E * (F + 2) if cfg["kind"] == "dcn" else E
-    p = _mlp_macs(n_dense, (*mlp, E)) + _mlp_macs(inter, (*mlp, 1))
-    if cfg["kind"] == "dcn":
-        p += 2 * int(cfg["cross_layers"]) * inter
-    return p
+def forward_flops(cfg: dict, rows: int, root=None) -> float:
+    """FLOPs of one forward pass over ``rows`` samples, by the
+    configuration's kind."""
+    return models.load(cfg["kind"], root).forward_flops(cfg, rows)
 
 
-def forward_flops(cfg: dict, rows: int) -> float:
-    """FLOPs of one forward pass over ``rows`` samples: the MLPs and the
-    interaction (2 per multiply-add), plus the pooling sums."""
-    E, mlp = int(cfg["embedding_dim"]), tuple(cfg["mlp_dims"])
-    t = cfg["tables"]
-    F, H = len(t["sizes"]), int(t["hist_max"])
-    macs = _mlp_macs(int(t["n_dense"]), (*mlp, E))
-    if cfg["kind"] == "dcn":
-        d = E * (F + 2)
-        macs += _mlp_macs(d, (*mlp, 1))
-        flops = 2 * macs + int(cfg["cross_layers"]) * 5 * d + H * E
-    else:
-        macs += _mlp_macs(E, (*mlp, 1))
-        flops = 2 * macs + (F + H) * E
-        if cfg["kind"] == "dfm":
-            flops += 3 * (F + 2) * E
-    return float(rows) * flops
-
-
-def train_step(cfg: dict, rows: int, distinct: float) -> dict:
+def train_step(cfg: dict, rows: int, distinct: float, root=None) -> dict:
     """A training step over ``rows`` samples touching ``distinct``
     embedding rows: forward and backward (3x the forward FLOPs), and
-    each touched row with its row-wise Adagrad accumulator, and each
-    dense parameter, read and written once."""
-    E = int(cfg["embedding_dim"])
-    wide = 1 if cfg["kind"] == "wdl" else 0
-    table = distinct * ((E + wide) + (1 + wide))     # rows + accumulators
-    dense = dense_params(cfg)
-    return {"flops": 3 * forward_flops(cfg, rows),
+    each touched row of every table leaf with its row-wise Adagrad
+    accumulator, and each dense parameter, read and written once."""
+    kind = models.load(cfg["kind"], root)
+    per_row = sum(width + 1 for width in kind.tables(cfg).values())
+    table = distinct * per_row                       # rows + accumulators
+    dense = kind.dense_params(cfg)
+    return {"flops": 3 * kind.forward_flops(cfg, rows),
             "bytes": 2 * F32 * (table + dense)}
 
 
