@@ -44,7 +44,7 @@ class CTRWorkload:
     """
 
     name: str
-    model: str                      # wdl | dfm | dcn  (paper Table 3)
+    model: str                      # wdl | dfm | dcn (paper Table 3) | dcnv2
     table_sizes: tuple[int, ...]    # ids per field
     zipf_a: tuple[float, ...]       # skew per field
     n_dense: int = 13
@@ -56,15 +56,28 @@ class CTRWorkload:
     # bandwidth-aware dispatch exploits.
     hist_max: int = 48
     hist_mean: float = 12.0
+    # ids per field, laid out bag by bag at the front of a sample row;
+    # () is one id per field
+    bag_sizes: tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if self.bag_sizes and len(self.bag_sizes) != self.n_fields:
+            raise ValueError(f"{self.name}: {len(self.bag_sizes)} bag sizes "
+                             f"for {self.n_fields} fields")
 
     @property
     def n_fields(self) -> int:
         return len(self.table_sizes)
 
     @property
+    def bags(self) -> tuple[int, ...]:
+        """Ids per field (1 each unless ``bag_sizes`` says otherwise)."""
+        return self.bag_sizes or (1,) * self.n_fields
+
+    @property
     def width(self) -> int:
-        """Columns of a sample row (fixed fields + history slots)."""
-        return self.n_fields + self.hist_max
+        """Columns of a sample row (the fields' bags + history slots)."""
+        return sum(self.bags) + self.hist_max
 
     @property
     def vocab(self) -> int:
@@ -75,22 +88,23 @@ class CTRWorkload:
         return np.concatenate([[0], np.cumsum(self.table_sizes)[:-1]]).astype(np.int64)
 
     def sample_batch(self, rng: np.random.Generator, batch: int) -> np.ndarray:
-        """(batch, F) flat (offset) ids with per-sample group locality."""
+        """(batch, width) flat (offset) ids with per-sample group locality:
+        each field's bag, then the history bag."""
         off = self.offsets()
         groups = rng.integers(0, self.n_groups, batch)
         cols = []
-        for f in range(self.n_fields):
+        for f, n in enumerate(self.bags):
             size = self.table_sizes[f]
-            ids = zipf_ids(rng, self.zipf_a[f], batch, size)
+            ids = zipf_ids(rng, self.zipf_a[f], batch * n, size)
             if size >= 10 * self.n_groups and self.group_frac > 0:
                 # group-local draw: same Zipf shape inside the group slice
                 slice_size = size // self.n_groups
-                local = zipf_ids(rng, self.zipf_a[f], batch, slice_size)
-                local = groups * slice_size + local
-                use_local = rng.random(batch) < self.group_frac
+                local = zipf_ids(rng, self.zipf_a[f], batch * n, slice_size)
+                local = np.repeat(groups, n) * slice_size + local
+                use_local = rng.random(batch * n) < self.group_frac
                 ids = np.where(use_local, local, ids)
-            cols.append(ids + off[f])
-        out = np.stack(cols, axis=1)
+            cols.append(ids.reshape(batch, n) + off[f])
+        out = np.concatenate(cols, axis=1)
         if self.hist_max:
             # variable-length multi-hot history over field 0's table
             size = self.table_sizes[0]
@@ -144,6 +158,28 @@ WORKLOADS: dict[str, CTRWorkload] = {
     "S3": _mk("S3", "dcn", big=150_000, small=2_000, n_big=3, n_small=23, a_big=1.2, a_small=1.15),
     # small variant for tests
     "tiny": _mk("tiny", "wdl", big=2_000, small=100, n_big=2, n_small=4, a_big=1.1, a_small=1.05),
+    # DLRM-DCNv2 as MLPerf Training runs it (mlcommons/training
+    # recommendation_v2/torchrec_dlrm, Criteo 1TB multi-hot): one chip's
+    # share of a 16-chip deployment.  The six tables over 1M rows are
+    # split row-wise over 16 chips (40M -> 2.5M, 3,067,956 -> 191,748);
+    # the 20 others are held whole.  Bags of the published
+    # --multi_hot_sizes, no history bag.
+    "dlrm-dcnv2": CTRWorkload(
+        name="dlrm-dcnv2", model="dcnv2",
+        table_sizes=(2_500_000, 39_060, 17_295, 7_424, 20_265, 3, 7_122,
+                     1_543, 63, 2_500_000, 191_748, 405_282, 10, 2_209,
+                     11_938, 155, 4, 976, 14, 2_500_000, 2_500_000,
+                     2_500_000, 590_152, 12_973, 108, 36),
+        zipf_a=tuple(1.2 if f in (0, 9, 10, 19, 20, 21) else 1.15
+                     for f in range(26)),
+        bag_sizes=(3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1,
+                   12, 100, 27, 10, 3, 1, 1),
+        hist_max=0),
+    "dcnv2-tiny": CTRWorkload(
+        name="dcnv2-tiny", model="dcnv2",
+        table_sizes=(2_000, 2_000, 100, 100, 100, 100),
+        zipf_a=(1.1, 1.1, 1.05, 1.05, 1.05, 1.05),
+        bag_sizes=(3, 1, 2, 1, 6, 1), hist_max=0),
 }
 
 

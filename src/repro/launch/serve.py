@@ -89,6 +89,11 @@ def build_parser():
 def run_serve(args) -> dict:
     cfg = DLRM_CONFIGS[args.arch]
     wl = WORKLOADS[cfg.workload]
+    if wl.bag_sizes:
+        # the stream's drift and the step's pooled history read a row as
+        # one id per field, then the history bag
+        raise SystemExit(f"{args.arch}: serving takes one id per field; "
+                         "this workload has per-field bags")
     n, V, F = args.workers, wl.vocab, wl.n_fields
     slo_s = args.slo_ms * 1e-3
     reg = MetricsRegistry()

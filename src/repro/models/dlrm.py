@@ -1,11 +1,12 @@
-"""DLRM models for the paper's workloads: WDL [12], DeepFM [24], DCN [66].
+"""DLRM models for the paper's workloads: WDL [12], DeepFM [24], DCN [66],
+and DLRM-DCNv2 as MLPerf Training runs it.
 
 One flat embedding table over the concatenated field vocabularies (ids are
 pre-offset by the data pipeline) — exactly the "global embedding table"
 that the PS holds in the paper; the ESD layer manages which rows live in
 which worker cache.  Dense features go through the bottom MLP; interaction
-is model-specific (wide linear / FM / cross network); top MLP emits the CTR
-logit.
+is model-specific (wide linear / FM / cross network / low-rank cross
+network); top MLP emits the CTR logit.
 """
 from __future__ import annotations
 
@@ -22,20 +23,32 @@ def init_params(key, cfg: DLRMConfig, workload: CTRWorkload):
     V, E = workload.vocab, cfg.embedding_dim
     F = workload.n_fields
     ks = jax.random.split(key, 10)
+    bias = cfg.kind == "dcnv2"
     p = {
         "embed": jax.random.normal(ks[0], (V, E), jnp.float32) * 0.01,
-        "bottom": _init_mlp(ks[1], workload.n_dense, (*cfg.mlp_dims, E)),
+        "bottom": _init_mlp(ks[1], workload.n_dense, cfg.bottom_widths, bias),
     }
     # interaction blocks: F single-hot fields + 1 pooled multi-hot history
-    # bag + 1 dense projection
-    inter_dim = {"wdl": E, "dfm": E, "dcn": E * (F + 2)}[cfg.kind]
-    p["top"] = _init_mlp(ks[2], inter_dim, (*cfg.mlp_dims, 1))
+    # bag + 1 dense projection; dcnv2: the bottom MLP's output + F pooled
+    # bags
+    inter_dim = {"wdl": E, "dfm": E, "dcn": E * (F + 2),
+                 "dcnv2": cfg.bottom_widths[-1] + E * F}[cfg.kind]
+    p["top"] = _init_mlp(ks[2], inter_dim, cfg.top_widths, bias)
     if cfg.kind == "wdl":
         p["wide"] = jax.random.normal(ks[3], (V, 1), jnp.float32) * 0.01
     if cfg.kind == "dcn":
         d = E * (F + 2)
         p["cross_w"] = jax.random.normal(ks[4], (cfg.cross_layers, d), jnp.float32) * (d ** -0.5)
         p["cross_b"] = jnp.zeros((cfg.cross_layers, d), jnp.float32)
+    if cfg.kind == "dcnv2":
+        # layer l: V (d -> r), W (r -> d) and the bias of W's output
+        p["cross"] = []
+        for l in range(cfg.cross_layers):
+            kv, kw = jax.random.split(jax.random.fold_in(ks[4], l))
+            p["cross"].append({
+                "v": init_linear(kv, inter_dim, cfg.cross_rank, jnp.float32)["w"],
+                "w": init_linear(kw, cfg.cross_rank, inter_dim, jnp.float32)["w"],
+                "b": jnp.zeros((inter_dim,), jnp.float32)})
     return p
 
 
@@ -62,27 +75,63 @@ def ps_stack_tables(params, part):
     return out
 
 
-def _init_mlp(key, din, dims):
+def _init_mlp(key, din, dims, bias=False):
     layers = []
     for i, dout in enumerate(dims):
         layers.append(init_linear(jax.random.fold_in(key, i), din, dout,
                                   jnp.float32))
+        if bias:
+            layers[-1]["b"] = jnp.zeros((dout,), jnp.float32)
         din = dout
     return layers
 
 
 def _mlp(layers, x):
+    """ReLU between the layers, none after the last; a layer with ``b``
+    adds it."""
     for i, lp in enumerate(layers):
         x = linear(lp, x)
+        if "b" in lp:
+            x = x + lp["b"]
         if i + 1 < len(layers):
             x = jax.nn.relu(x)
     return x
 
 
+def bag_pool(rows, bags):
+    """Sum-pool (B, sum(bags), E) rows into (B, len(bags), E): field f's
+    bag is the next ``bags[f]`` columns (a static layout)."""
+    out, o = [], 0
+    for n in bags:
+        out.append(rows[:, o:o + n].sum(axis=1))
+        o += n
+    return jnp.stack(out, axis=1)
+
+
+def _dcnv2(params, bags, sparse_ids, dense, emb_all):
+    """DLRM-DCNv2 (TorchRec DLRM_DCN): every field's bag sum-pooled; x0 the
+    bottom MLP's output (ReLU on every layer) then the F pooled rows;
+    ``x_{l+1} = x0 * (W_l (V_l x_l) + b_l) + x_l``; the top MLP's logit."""
+    with jax.named_scope("dlrm.bag_pool"):
+        if emb_all is None:
+            valid = sparse_ids >= 0
+            ids = jnp.where(valid, sparse_ids, 0)
+            emb_all = _flat_table(params["embed"])[ids] * valid[..., None]
+        pooled = bag_pool(emb_all, bags)               # (B, F, E)
+    d = jax.nn.relu(_mlp(params["bottom"], dense))
+    x0 = jnp.concatenate([d, pooled.reshape(pooled.shape[0], -1)], axis=-1)
+    x = x0
+    with jax.named_scope("dlrm.cross"):
+        for lp in params["cross"]:
+            x = x0 * ((x @ lp["v"]) @ lp["w"] + lp["b"]) + x
+    return _mlp(params["top"], x)[:, 0]
+
+
 def forward(params, cfg: DLRMConfig, sparse_ids, dense, n_fields=None,
             emb_all=None):
     """sparse_ids: (B, W) flat ids (W = fixed fields + multi-hot history
-    slots, PAD=-1); dense: (B, n_dense) -> logits (B,).
+    slots, PAD=-1; ``dcnv2``: the fields' bags, ``CTRWorkload.bag_sizes``);
+    dense: (B, n_dense) -> logits (B,).
 
     Multi-PS: the tables may arrive PS-stacked as (n_ps, max_rows, ...)
     (repro.ps convention) with ids already PS-linearized — the stack
@@ -95,6 +144,9 @@ def forward(params, cfg: DLRMConfig, sparse_ids, dense, n_fields=None,
     bitwise.
     """
     from ..data.synthetic import WORKLOADS
+    if cfg.kind == "dcnv2":
+        return _dcnv2(params, WORKLOADS[cfg.workload].bags, sparse_ids, dense,
+                      emb_all)
     F = n_fields if n_fields is not None else WORKLOADS[cfg.workload].n_fields
     F = min(F, sparse_ids.shape[1])
     valid = sparse_ids >= 0
@@ -126,9 +178,10 @@ def forward(params, cfg: DLRMConfig, sparse_ids, dense, n_fields=None,
     if cfg.kind == "dcn":
         x0 = jnp.concatenate([emb.reshape(emb.shape[0], -1), d], axis=-1)
         x = x0
-        for l in range(cfg.cross_layers):
-            xw = x @ params["cross_w"][l]              # (B,)
-            x = x0 * xw[:, None] + params["cross_b"][l][None] + x
+        with jax.named_scope("dlrm.cross"):
+            for l in range(cfg.cross_layers):
+                xw = x @ params["cross_w"][l]          # (B,)
+                x = x0 * xw[:, None] + params["cross_b"][l][None] + x
         return _mlp(params["top"], x)[:, 0]
     raise ValueError(cfg.kind)
 

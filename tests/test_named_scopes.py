@@ -77,3 +77,82 @@ def test_compiled_stage_carries_its_scopes(compiled, stage, scopes):
     if stage == "advance":
         assert _has_scope({n for n in names if "capacity_cut" in n
                            and n.endswith("/sort")}, "esd.cache_update")
+
+
+_DEF = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = [a-z0-9]+\[([\d,]*)\]")
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+
+
+def cross_products(hlo_text, d, r):
+    """The op_name under which each matrix product of a low-rank cross
+    (the ``dot`` or ``convolution`` whose operands and output span both
+    the cross's width ``d`` and its rank ``r``) is filed: that of the
+    outermost fusion that holds it, as the device trace names it."""
+    comp, owner, dims, caller, products = None, {}, {}, {}, []
+    for line in hlo_text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _DEF.match(line)
+        if m:
+            dims[m.group(1)] = {int(x) for x in m.group(2).split(",") if x}
+        name = line.strip().removeprefix("ROOT ").partition(" = ")[0]
+        owner[name.lstrip("%")] = comp
+        call = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        if call:
+            caller[call.group(1)] = line
+        op = re.search(r" (dot|convolution)\((%[\w.\-]+), (%[\w.\-]+)\)",
+                       line)
+        if op and m:
+            spans = dims[m.group(1)].union(
+                *(dims.get(a.lstrip("%"), set()) for a in op.groups()[1:]))
+            if {d, r} <= spans:
+                products.append((comp, line))
+    out = []
+    for comp, line in products:
+        while comp in caller:
+            line = caller[comp]
+            comp = owner[line.strip().removeprefix("ROOT ").partition(
+                " = ")[0].lstrip("%")]
+        out.append(re.search(r'op_name="([^"]*)"', line).group(1))
+    return out
+
+
+def assert_cross_scoped(names, layers):
+    """Two products a layer forward and four backward (the transposes),
+    every one filed under ``dlrm.cross``."""
+    assert all(_has_scope({n}, "dlrm.cross") for n in names), names
+    backward = [n for n in names if "transpose(" in n]
+    assert (len(names) - len(backward), len(backward)) == (
+        2 * layers, 4 * layers), names
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("dcn-tiny", ("dlrm.cross",)),
+    ("dcnv2-tiny", ("dlrm.bag_pool", "dlrm.cross")),
+])
+def test_train_step_names_its_interaction(arch, scopes):
+    """The bags and the cross net, forward and backward, under their own
+    scopes inside the train step; the low-rank cross's every matrix
+    product among them."""
+    cfg = DLRM_CONFIGS[arch]
+    wl = WORKLOADS[cfg.workload]
+    rng = np.random.default_rng(0)
+    optimizer = get_optimizer("rowwise_adagrad", 1e-2)
+    params = dlrm.init_params(jax.random.key(0), cfg, wl)
+    train_jit = make_dlrm_train_jit(cfg, optimizer, dlrm.bce_loss)
+    # 5 rows: a batch size that no width of the tiny models shares
+    text = train_jit.lower(
+        params, optimizer.init(params),
+        jnp.asarray(wl.sample_batch(rng, 5), jnp.int32),
+        jnp.asarray(wl.dense_batch(rng, 5)),
+        jnp.asarray(wl.label_batch(rng, 5))).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert _has_scope(names, scope), scope
+        assert _has_scope({n for n in names if "transpose(" in n}, scope)
+    if cfg.kind == "dcnv2":
+        d = cfg.bottom_widths[-1] + cfg.embedding_dim * wl.n_fields
+        assert_cross_scoped(cross_products(text, d, cfg.cross_rank),
+                            cfg.cross_layers)

@@ -18,7 +18,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import DLRM_CONFIGS
 from repro.data.synthetic import WORKLOADS
+from repro.launch.steps import make_dlrm_train_jit
+from repro.models import dlrm
+from repro.optim import get_optimizer
+from test_named_scopes import assert_cross_scoped, cross_products
 from repro.kernels.emb_lookup import (pooled_lookup, pooled_lookup_quant,
                                       pooled_lookup_staged, staged_gather)
 from repro.kernels.exchange_pack import (gather_rows_pallas,
@@ -104,3 +109,28 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= temp_budget, (name, temp)
+
+
+def test_dcnv2_cross_fusions_carry_their_scope(one_chip):
+    """At the published widths, as XLA fuses the train step for a v5e:
+    every fusion that holds a product of the low-rank cross, forward and
+    backward, is filed under ``dlrm.cross``, the scope that
+    ``cross_ms.train`` reads from the device trace (the weight-gradient
+    products fuse with the optimizer's squares, and keep the name)."""
+    cfg = DLRM_CONFIGS["dlrm-dcnv2"]
+    wl = WORKLOADS[cfg.workload]
+    optimizer = get_optimizer("rowwise_adagrad", 1e-2)
+    params = jax.eval_shape(
+        lambda: dlrm.init_params(jax.random.key(0), cfg, wl))
+    state = jax.eval_shape(optimizer.init, params)
+    train_jit = make_dlrm_train_jit(cfg, optimizer, dlrm.bce_loss_masked)
+    B = 128
+    args = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                        (params, state))
+    text = train_jit.lower(
+        *args, _sds((B, wl.width), jnp.int32, one_chip),
+        _sds((B, wl.n_dense), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip)).compile().as_text()
+    d = cfg.bottom_widths[-1] + cfg.embedding_dim * wl.n_fields
+    assert_cross_scoped(cross_products(text, d, cfg.cross_rank),
+                        cfg.cross_layers)
