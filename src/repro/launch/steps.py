@@ -11,6 +11,7 @@ import numpy as np
 
 from ..configs.base import INPUT_SHAPES, ModelConfig, ShapeConfig
 from ..models import api
+from ..models.dlrm import TABLE_LEAVES, _flat_table
 from ..optim import Optimizer, get_optimizer
 
 
@@ -120,6 +121,29 @@ def raise_on_overflow(counts: dict) -> None:
             f"or fix the assignment)")
 
 
+def _step_rows(sparse, n_rows: int):
+    """The step's distinct ids and each id's position among them.
+
+    ``uids`` (B*W,) holds the distinct non-PAD ids, sorted, then fill
+    slots ``n_rows, n_rows + 1, ...``: out of range, so a gather from them
+    reads zeros and a scatter to them is dropped, and distinct, so no two
+    writes of a scatter meet (where two did, which one lands would be
+    unspecified).  ``pos`` has the shape of ``sparse``: an id's index in
+    ``uids``, PAD (-1) kept.  Sorts only: on the TPU a scatter costs per
+    index, and ``jnp.unique`` builds its inverse with two."""
+    valid = sparse >= 0
+    ids = jnp.where(valid, sparse, n_rows).reshape(-1)
+    slot = jax.lax.iota(jnp.int32, ids.size)
+    ids, order = jax.lax.sort((ids, slot), num_keys=1)
+    first = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+    rank = jnp.cumsum(first.astype(jnp.int32)) - 1
+    uids = jax.lax.sort(jnp.where(first & (ids < n_rows), ids,
+                                  jnp.iinfo(jnp.int32).max))
+    uids = jnp.where(uids < n_rows, uids, n_rows + slot)
+    _, pos = jax.lax.sort((order, rank), num_keys=1)
+    return uids, jnp.where(valid, pos.reshape(sparse.shape), -1)
+
+
 def make_dlrm_train_jit(cfg, optimizer: Optimizer, loss_fn, part=None):
     """The jitted DLRM train step
 
@@ -131,7 +155,26 @@ def make_dlrm_train_jit(cfg, optimizer: Optimizer, loss_fn, part=None):
     space first.  Its device time carries stable scope names:
     ``dlrm.train_step`` over ``dlrm.forward`` (the loss; its transposes
     are the backward) and ``optim.update``.
+
+    The tables (``dlrm.TABLE_LEAVES``; a PS-stacked one through its flat
+    view) are updated on the step's distinct ids only, not on all their
+    rows: the loss runs on the touched rows, gathered into (U, E) leaves,
+    with each id replaced by its row's position, so autodiff sums a row's
+    duplicate gradients (before the optimizer squares them); the
+    optimizer updates those rows and their state, which are scattered
+    back.  This equals the update of every row only for an optimizer
+    that is row-separable and leaves a row with zero gradient, and its
+    state, unchanged (``Optimizer.row_sparse``: SGD and row-wise
+    Adagrad); any other is refused.  The padding of the distinct ids is
+    out of range (``_step_rows``), so its rows write nothing back.  The
+    dedup, the gathers and the scatters run under ``optim.update``; the
+    other leaves take the dense update.
     """
+    if not optimizer.row_sparse:
+        raise ValueError("the DLRM train step updates only the rows a step "
+                         "touches, which needs a row-separable optimizer "
+                         "that leaves a row with zero gradient unchanged "
+                         "(sgd, rowwise_adagrad)")
     forward = jax.named_scope("dlrm.forward")(loss_fn)
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -139,11 +182,36 @@ def make_dlrm_train_jit(cfg, optimizer: Optimizer, loss_fn, part=None):
     def train_jit(params, opt_state, sparse, dense, labels):
         if part is not None:
             sparse = part.to_linear(sparse)
-        loss, grads = jax.value_and_grad(forward)(params, cfg, sparse, dense,
+        tables = [k for k in TABLE_LEAVES if k in params]
+        with jax.named_scope("optim.update"):
+            uids, pos = _step_rows(sparse,
+                                   _flat_table(params["embed"]).shape[0])
+
+            # no sorted or unique hints on these: with them, the TPU's
+            # scatter into a whole table took 10x to 20x longer
+            def rows(flat):
+                return flat.at[uids].get(mode="fill", fill_value=0)
+
+            def put(flat, new_rows, shape):
+                return flat.at[uids].set(new_rows,
+                                         mode="drop").reshape(shape)
+
+            touched = params | {k: rows(_flat_table(params[k]))
+                                for k in tables}
+            # row-wise state mirrors the parameters, one entry a row; SGD
+            # keeps none
+            touched_state = opt_state and opt_state | {
+                k: rows(opt_state[k].reshape(-1)) for k in tables}
+        loss, grads = jax.value_and_grad(forward)(touched, cfg, pos, dense,
                                                   labels)
         with jax.named_scope("optim.update"):
-            params, opt_state = optimizer.update(grads, opt_state, params)
-        return params, opt_state, loss
+            new, new_state = optimizer.update(grads, touched_state, touched)
+            for k in tables:
+                new[k] = put(_flat_table(params[k]), new[k], params[k].shape)
+                if opt_state:
+                    new_state[k] = put(opt_state[k].reshape(-1), new_state[k],
+                                       opt_state[k].shape)
+        return new, new_state, loss
 
     return train_jit
 

@@ -210,7 +210,7 @@ def run_dlrm(args):
     # g_hat so its per-row accumulator tracks reality.  codec=None never
     # builds or calls this function — train_jit above stays the bitwise
     # fp32 path.
-    quant_keys = tuple(k for k in ("embed", "wide") if k in params)
+    quant_keys = tuple(k for k in dlrm.TABLE_LEAVES if k in params)
     qres = (jax.device_put({k: jnp.zeros_like(params[k]) for k in quant_keys},
                            {k: shardings[k] for k in quant_keys})
             if codec is not None else None)

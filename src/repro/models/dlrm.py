@@ -52,6 +52,10 @@ def init_params(key, cfg: DLRMConfig, workload: CTRWorkload):
     return p
 
 
+# The leaves indexed by the sparse ids: one row per id
+TABLE_LEAVES = ("embed", "wide")
+
+
 def _flat_table(tbl):
     """A lookup view of a table: PS-stacked (n_ps, max_rows, E) flattens
     so PS-linearized ids index it directly; flat (V, E) passes through."""
@@ -65,7 +69,7 @@ def ps_stack_tables(params, part):
     lookups use PS-linearized ids against the flattened stack)."""
     out = dict(params)
     lin = np.asarray(part.to_linear(np.arange(part.vocab)))
-    for name in ("embed", "wide"):
+    for name in TABLE_LEAVES:
         if name not in params:
             continue
         tbl = params[name]

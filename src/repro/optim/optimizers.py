@@ -21,6 +21,11 @@ __all__ = ["sgd", "adam", "rowwise_adagrad", "Optimizer"]
 class Optimizer:
     init: Callable[[Any], Any]
     update: Callable[[Any, Any, Any], tuple[Any, Any]]  # (grads, state, params)
+    # The update may run on a table's touched rows alone: it is
+    # row-separable, a row whose gradient is zero keeps its value and its
+    # state exactly, and the state is empty or mirrors the parameters leaf
+    # for leaf with one entry per row (repro.launch.steps.make_dlrm_train_jit)
+    row_sparse: bool = False
 
 
 def sgd(lr: float = 1e-2) -> Optimizer:
@@ -33,7 +38,7 @@ def sgd(lr: float = 1e-2) -> Optimizer:
                            params, grads)
         return new, state
 
-    return Optimizer(init, update)
+    return Optimizer(init, update, row_sparse=True)
 
 
 def adam(lr: float = 3e-4, b1: float = 0.9, b2: float = 0.95,
@@ -70,7 +75,17 @@ def rowwise_adagrad(lr: float = 1e-2, eps: float = 1e-10) -> Optimizer:
 
     A "row" is everything but the trailing (embedding) dim, so a
     PS-stacked (n_ps, max_rows, E) table gets per-(shard, local_row)
-    accumulators — identical to rank-2 behavior for ordinary (V, E)."""
+    accumulators — identical to rank-2 behavior for ordinary (V, E).
+
+    The update is row-separable (a row's step reads only its own gradient
+    and accumulator), and a row whose gradient is zero is left exactly as
+    it was: its accumulator gains ``mean(0**2) = 0`` and its value loses
+    ``lr * 0 * rsqrt(a + eps)``, which is 0 because ``eps > 0`` keeps the
+    rsqrt finite.  So updating only the rows a step touched gives the
+    same result as updating every row (``row_sparse``).  The accumulator
+    adds the mean square of a row's *summed* gradient, so duplicate ids
+    of a batch must be summed before the update, not squared one by
+    one."""
 
     def init(params):
         def acc(p):
@@ -99,7 +114,7 @@ def rowwise_adagrad(lr: float = 1e-2, eps: float = 1e-10) -> Optimizer:
         accs = tdef.unflatten([o[1] for o in out])
         return new, accs
 
-    return Optimizer(init, update)
+    return Optimizer(init, update, row_sparse=True)
 
 
 def get_optimizer(name: str, lr: float) -> Optimizer:

@@ -4,9 +4,12 @@ The only test file that describes the chip.  Each test compiles one
 kernel at the paper's embedding width (E = 512) against the S1 table
 (502k rows, ~1.03 GB f32) for one chip of a described ``v5e:2x2``
 topology, with ``interpret=False``: Mosaic's tiling, memory-space and
-VMEM checks run here, with no chip attached.  Nothing runs, so these
-tests say nothing about results or times — tests/test_kernels.py and
-friends check results in interpret mode, ``chip_smoke.py`` on the chip.
+VMEM checks run here, with no chip attached.  The last tests compile
+whole train steps at their registered sizes: the scopes of the DCNv2
+cross, and the temporaries of the update of the step's rows.  Nothing
+runs, so these tests say nothing about results or times —
+tests/test_kernels.py and friends check results in interpret mode,
+``chip_smoke.py`` on the chip.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library at a time, and
@@ -134,3 +137,28 @@ def test_dcnv2_cross_fusions_carry_their_scope(one_chip):
     d = cfg.bottom_widths[-1] + cfg.embedding_dim * wl.n_fields
     assert_cross_scoped(cross_products(text, d, cfg.cross_rank),
                         cfg.cross_layers)
+
+
+@pytest.mark.parametrize("arch", ["wdl-s1", "dlrm-dcnv2"])
+def test_train_step_temporaries_stay_off_the_table(one_chip, arch):
+    """At the program's registered sizes and the published widths, 128
+    samples a step: the train step's temporaries are under a tenth of the
+    embedding table's bytes, so the tables are updated on the step's
+    rows and no gradient of a whole table is built."""
+    cfg = DLRM_CONFIGS[arch]
+    wl = WORKLOADS[cfg.workload]
+    optimizer = get_optimizer("rowwise_adagrad", 1e-2)
+    params = jax.eval_shape(
+        lambda: dlrm.init_params(jax.random.key(0), cfg, wl))
+    state = jax.eval_shape(optimizer.init, params)
+    train_jit = make_dlrm_train_jit(cfg, optimizer, dlrm.bce_loss)
+    B = 128
+    args = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                        (params, state))
+    compiled = train_jit.lower(
+        *args, _sds((B, wl.width), jnp.int32, one_chip),
+        _sds((B, wl.n_dense), jnp.float32, one_chip),
+        _sds((B,), jnp.float32, one_chip)).compile()
+    table = wl.vocab * cfg.embedding_dim * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < table / 10, (arch, temp, table)
